@@ -16,11 +16,13 @@ REPRO_BENCH_FULL=1 enables the paper-scale sweeps.
 
 from __future__ import annotations
 
+import sys
 import time
 import traceback
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
+    """Run every section; returns 1 if any section raised, else 0."""
     from benchmarks import (
         common,
         fig4_jct_vs_racks,
@@ -32,7 +34,11 @@ def main(argv=None) -> None:
         train_bench,
     )
 
+    from repro.launch.compile_cache import enable_compile_cache
+
     args = common.bench_arg_parser(__doc__).parse_args(argv)
+    enable_compile_cache()
+    failed = []
     print("name,us_per_call,derived")
     for mod in (
         fig4_jct_vs_racks,
@@ -51,12 +57,16 @@ def main(argv=None) -> None:
                 1e6 * (time.perf_counter() - t0),
                 "ok",
             )
-        except Exception:  # noqa: BLE001 — keep the harness running
+        except Exception:  # noqa: BLE001 — run the other sections, then fail
             traceback.print_exc()
             common.emit(f"_section_{mod.__name__.split('.')[-1]}", 0, "FAILED")
+            failed.append(mod.__name__)
     if args.json:
         common.write_json(args.json, bench="all")
+    if failed:
+        print(f"FAILED sections: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

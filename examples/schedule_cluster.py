@@ -15,9 +15,11 @@ import numpy as np
 from repro.core import ProblemInstance, random_job, schedule_fleet, solve_bnb, wired_only
 from repro.distribution.plan import LinkSpec, backward_profile, replan
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     n_jobs = 8
     total0, total2, proved = 0.0, 0.0, 0
     print(f"scheduling {n_jobs} periodic jobs (tasks ~ U[5,10], rho=0.5) ...")

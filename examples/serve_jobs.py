@@ -10,6 +10,7 @@ compaction and streaming-only stats — bit-identical JCTs, O(1) memory.
 Run:  PYTHONPATH=src python examples/serve_jobs.py
 """
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.online import (
     OnlineScheduler,
     production_arrivals,
@@ -24,6 +25,7 @@ SOLVER = dict(
 
 
 def main() -> None:
+    enable_compile_cache()
     arrivals = production_arrivals(
         seed=0, rate=1 / 40, n_jobs=10, min_rack_demand=4, **CLUSTER
     )

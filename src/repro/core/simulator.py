@@ -25,6 +25,7 @@ from repro.core.schedule import Schedule
 
 __all__ = [
     "simulate",
+    "greedy_makespan",
     "seed_channel_timelines",
     "critical_path_priority",
     "build_op_tables",
@@ -462,3 +463,56 @@ def simulate(
 
         check_feasible(inst, sched)
     return sched
+
+
+def greedy_makespan(
+    inst: ProblemInstance, rack: np.ndarray, use_wireless: bool = True
+) -> np.float32:
+    """Host reference of the batched stage-2 evaluator's score for one row.
+
+    The vectorized engine scores a candidate with a non-delay greedy pass
+    that is not :func:`simulate`: it walks the static op table of
+    :func:`build_op_tables` in order, appends each operation at the end of
+    its rack or channel (no gap insertion, no priority heap), sends a cross
+    edge on the earliest-finishing channel both racks reach (wired first on
+    ties), and computes in float32. This loop restates those semantics
+    plainly, so that the device scores can be checked exactly.
+    """
+    job = inst.job
+    rack = np.asarray(rack, dtype=np.int64)
+    tables = build_op_tables(inst)
+    f32 = np.float32
+    p = job.p.astype(f32)
+    q, qw, r = (
+        np.asarray(a, f32) for a in (inst.q_wired, inst.q_wireless, inst.r_local)
+    )
+    reach = None if inst.topology is None else inst.topology.reach
+    n_wireless = inst.n_wireless if use_wireless else 0
+
+    rack_free = np.zeros(inst.n_racks, f32)
+    chan_free = np.zeros(1 + n_wireless, f32)  # 0 = wired, 1 + k = subchannel k
+    task_fin = np.zeros(job.n_tasks, f32)
+    edge_fin = np.zeros(job.n_edges, f32)
+    for kind, i in zip(tables.kind, tables.idx):
+        if kind == OP_TASK:
+            ins = tables.task_in_edges[i]
+            ready = max([f32(0.0)] + [edge_fin[e] for e in ins if e >= 0])
+            fin = max(ready, rack_free[rack[i]]) + p[i]
+            rack_free[rack[i]] = fin
+            task_fin[i] = fin
+            continue
+        u, v = int(tables.edge_src[i]), int(tables.edge_dst[i])
+        ready = task_fin[u]
+        if rack[u] == rack[v]:
+            edge_fin[i] = ready + r[i]
+            continue
+        best_c, best_fin = 0, max(ready, chan_free[0]) + q[i]
+        for k in range(n_wireless):
+            if reach is not None and not (reach[rack[u], k] and reach[rack[v], k]):
+                continue
+            fin = max(ready, chan_free[1 + k]) + qw[i]
+            if fin < best_fin:
+                best_c, best_fin = 1 + k, fin
+        chan_free[best_c] = best_fin
+        edge_fin[i] = best_fin
+    return f32(task_fin.max()) if job.n_tasks else f32(0.0)

@@ -309,7 +309,6 @@ def _compiled_evaluator(n_dev: int, m_pad: int, M_pad: int, n_chan: int):
     if n_dev <= 1:
         return jax.jit(core)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     # Local devices only: batch padding by the callers is sized to divide by
@@ -317,13 +316,13 @@ def _compiled_evaluator(n_dev: int, m_pad: int, M_pad: int, n_chan: int):
     # Only the candidate rows are sharded; tables are replicated.
     mesh = Mesh(np.asarray(jax.local_devices()), ("b",))
     r2, r3 = P(None, None), P(None, None, None)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         core,
         mesh=mesh,
         in_specs=(P("b", None), P("b"), r2, r2, r2, r2, r2, r2, r2, r2, r2,
                   r3, r2, r3),
         out_specs=P("b"),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -449,9 +448,7 @@ def _build_lb_arrays(instances, dims: _FleetDims):
     return tuple(jnp.asarray(a) for a in out)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("M_pad", "n_iters", "block_b", "contention")
-)
+@functools.partial(jax.jit, static_argnames=("M_pad", "n_iters", "contention"))
 def _fleet_lb_device(
     racks,      # int32[B, n_pad]
     inst_id,    # int32[B]
@@ -469,7 +466,6 @@ def _fleet_lb_device(
     *,
     M_pad: int,
     n_iters: int,
-    block_b: int,
     contention: bool,
 ):
     """Batched combined §IV-A bound: one device program for the whole fleet.
@@ -585,16 +581,13 @@ def _fleet_lb_device(
 
     from repro.kernels import ops as kops
 
-    return kops.batched_combined_lb(
-        w, p_b, extra, mask=mask, block_b=min(block_b, B), n_iters=n_iters
-    )
+    return kops.batched_combined_lb(w, p_b, extra, mask=mask, n_iters=n_iters)
 
 
 def batched_lower_bound(
     inst: ProblemInstance,
     racks: np.ndarray,
     use_kernel: bool = False,
-    block_b: int = 1024,
     contention: bool = True,
 ) -> np.ndarray:
     """Combined §IV-A LB per assignment (critical path + contention terms).
@@ -631,7 +624,6 @@ def batched_lower_bound(
             *lb_args,
             M_pad=dims.M_pad,
             n_iters=dims.n_iters,
-            block_b=min(block_b, B_pad),
             contention=contention,
         )
         return np.asarray(out)[:B]
@@ -1040,7 +1032,6 @@ def _run_fleet(
                         *lb_args,
                         M_pad=dims.M_pad,
                         n_iters=dims.n_iters,
-                        block_b=min(1024, B1),
                         contention=contention,
                     )
                 )

@@ -10,7 +10,9 @@ path from any source to each node — by n-1 Bellman relaxation rounds:
 Each round is a max-plus matrix-vector product, mapped to VPU broadcast
 adds + row-max reductions on a [bb, n, n] VMEM block. Graphs are padded to
 the TPU lane width (n <= 128) — the paper's production jobs have <= 10
-tasks, so thousands of candidate assignments evaluate in one launch.
+tasks, so thousands of candidate assignments evaluate in one launch. The
+row block ``bb`` is derived from ``n`` (:func:`block_rows`) so that the
+lane-padded block fits the chip's scoped VMEM.
 
 Two entry points share the relaxation loop:
 
@@ -35,9 +37,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["batched_critical_path", "batched_combined_lb"]
+__all__ = ["batched_critical_path", "batched_combined_lb", "block_rows"]
 
 NEG_INF = -1e30
+
+# Bytes of one lane-padded f32 (bb, n, n) input block. Mosaic pads the minor
+# (n, n) dims to (8k, 128k) tiles, and every such input is double-buffered
+# next to the relaxation temporaries. On TPU v5e the masked kernel (two such
+# inputs) stops compiling between 2 and 3 MiB per block at n = 64, and both
+# kernels at 8 MiB for every n; 1 MiB keeps at least 2x headroom at every
+# n bucket.
+_BLOCK_BYTES = 1 << 20
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def block_rows(B: int, n: int) -> int:
+    """Batch rows per grid step for a [B, n, n] launch: the whole batch if it
+    fits the VMEM block budget, else the largest multiple of 8 that does
+    (at least 8)."""
+    row_bytes = _round_up(n, 8) * _round_up(n, 128) * 4
+    return min(B, max(8, _BLOCK_BYTES // row_bytes // 8 * 8))
 
 
 def _relax(w, bb: int, n: int, n_iters: int):
@@ -60,7 +82,7 @@ def _kernel(w_ref, o_ref, *, n: int, bb: int, n_iters: int):
 @functools.partial(jax.jit, static_argnames=("block_b", "n_iters", "interpret"))
 def batched_critical_path(
     w: jax.Array,  # [B, n, n] float32 max-plus adjacency (-inf = no edge)
-    block_b: int = 8,
+    block_b: int | None = None,
     n_iters: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -68,13 +90,14 @@ def batched_critical_path(
 
     ``n_iters`` bounds the relaxation count (default n-1, the worst-case DAG
     depth). Callers that pad graphs to a size bucket should pass the true
-    depth bound so padding does not add rounds.
+    depth bound so padding does not add rounds. ``block_b`` (rows per grid
+    step) defaults to :func:`block_rows`; it changes no row's arithmetic.
     """
     B, n, _ = w.shape
     if n_iters is None:
         n_iters = n - 1
     n_iters = max(0, min(n_iters, n - 1))
-    bb = min(block_b, B)
+    bb = block_rows(B, n) if block_b is None else min(block_b, B)
     pad = (-B) % bb
     w = jnp.where(jnp.isfinite(w), w, NEG_INF).astype(jnp.float32)
     if pad:
@@ -117,7 +140,7 @@ def batched_combined_lb(
     p: jax.Array,      # [B, n] float32 per-row task durations (0 on padding)
     extra: jax.Array,  # [B] or [B, 1] float32 contention bound (-inf to disable)
     mask: jax.Array | None = None,  # [B, n, n] float32 feasibility uplift
-    block_b: int = 8,
+    block_b: int | None = None,
     n_iters: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -127,7 +150,8 @@ def batched_combined_lb(
     relaxation of :func:`batched_critical_path` plus a fused epilogue that
     adds the sink task duration (max_v dist[v] + p[v]) and maxes in the
     per-row ``extra`` contention terms, so one kernel launch emits the final
-    admissible bound. ``n_iters`` as in :func:`batched_critical_path`.
+    admissible bound. ``n_iters`` and ``block_b`` as in
+    :func:`batched_critical_path`.
 
     ``mask`` is the topology layer's matching-feasibility mask in additive
     form: 0 where the row's placement of edge (u, v) can reach a common
@@ -141,7 +165,7 @@ def batched_combined_lb(
     if n_iters is None:
         n_iters = n - 1
     n_iters = max(0, min(n_iters, n - 1))
-    bb = min(block_b, B)
+    bb = block_rows(B, n) if block_b is None else min(block_b, B)
     pad = (-B) % bb
     w = jnp.where(jnp.isfinite(w), w, NEG_INF).astype(jnp.float32)
     p = p.astype(jnp.float32)

@@ -73,8 +73,6 @@ def decode_attention_fwd(
     block_kv: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    from repro.kernels.ops import tpu_compiler_params
-
     B, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -106,7 +104,9 @@ def decode_attention_fwd(
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(kvlen_f, qf, kf, vf)
     return out.reshape(B, KV, G, D).reshape(B, H, D)

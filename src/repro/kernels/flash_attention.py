@@ -84,8 +84,6 @@ def flash_attention_fwd(
     block_kv: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    from repro.kernels.ops import tpu_compiler_params
-
     B, S, H, D = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -122,7 +120,9 @@ def flash_attention_fwd(
             pltpu.VMEM((bq * G,), jnp.float32),
             pltpu.VMEM((bq * G,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(qf, kf, vf)
     return (

@@ -1,7 +1,7 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU hosts (this container, and any unit-test environment) the kernels run
-in ``interpret=True`` mode — the kernel body executes as traced JAX ops, so
+On CPU hosts (any unit-test environment) the kernels run in
+``interpret=True`` mode — the kernel body executes as traced JAX ops, so
 correctness is identical while TPU Mosaic lowering is exercised only on real
 hardware. The wrapper picks the mode from the default backend.
 """
@@ -9,21 +9,6 @@ hardware. The wrapper picks the mode from the default backend.
 from __future__ import annotations
 
 import jax
-
-
-def tpu_compiler_params(dimension_semantics: tuple[str, ...]):
-    """Version-compat constructor for Pallas TPU compiler params.
-
-    Newer JAX exposes ``pltpu.CompilerParams``; the pinned 0.4.x series calls
-    it ``TPUCompilerParams``.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(dimension_semantics=dimension_semantics)
-
 
 from repro.kernels.cpm import batched_combined_lb as _combined_lb
 from repro.kernels.cpm import batched_critical_path as _cpm
@@ -35,7 +20,6 @@ __all__ = [
     "decode_attention",
     "batched_critical_path",
     "batched_combined_lb",
-    "tpu_compiler_params",
 ]
 
 
@@ -54,11 +38,11 @@ def decode_attention(q, k, v, kv_len, block_kv=512):
     return _decode(q, k, v, kv_len, block_kv=block_kv, interpret=_interpret())
 
 
-def batched_critical_path(w, block_b=8, n_iters=None):
+def batched_critical_path(w, block_b=None, n_iters=None):
     return _cpm(w, block_b=block_b, n_iters=n_iters, interpret=_interpret())
 
 
-def batched_combined_lb(w, p, extra, mask=None, block_b=8, n_iters=None):
+def batched_combined_lb(w, p, extra, mask=None, block_b=None, n_iters=None):
     return _combined_lb(
         w, p, extra, mask=mask, block_b=block_b, n_iters=n_iters,
         interpret=_interpret(),

@@ -24,7 +24,7 @@ import dataclasses
 import re
 from typing import Any
 
-__all__ = ["analyze_hlo", "xla_cost_analysis", "HloCost"]
+__all__ = ["analyze_hlo", "HloCost"]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
@@ -258,20 +258,6 @@ def _called(ins: Instr) -> list[str]:
             for name in m.group(1).split(","):
                 out.append(name.strip().lstrip("%"))
     return out
-
-
-def xla_cost_analysis(compiled: Any) -> dict[str, float]:
-    """XLA's own ``Compiled.cost_analysis()``, normalized across JAX versions.
-
-    Older releases return a per-partition ``[dict]`` list, newer ones a flat
-    dict. Always returns a (possibly empty) dict so callers can compare the
-    backend numbers against :func:`analyze_hlo` — which multiplies through
-    while-loop trip counts where XLA's analysis counts loop bodies once.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
 
 
 def analyze_hlo(text: str) -> HloCost:

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.hlo_analysis import analyze_hlo, xla_cost_analysis
+from repro.launch.hlo_analysis import analyze_hlo
 from repro.distribution.plan import (
     LinkSpec,
     backward_profile,
@@ -26,7 +26,7 @@ def test_analyzer_multiplies_scan_trip_counts():
     cost = analyze_hlo(compiled.as_text())
     assert cost.flops == pytest.approx(10 * 2 * 64**3)
     # XLA's own analysis is known NOT to multiply (the reason this exists).
-    xla = xla_cost_analysis(compiled).get("flops", 0.0)
+    xla = compiled.cost_analysis().get("flops", 0.0)
     assert xla < cost.flops / 2
 
 

@@ -931,20 +931,26 @@ def _run_fleet(
     fleet run traces (at most) one program per stage no matter how pruning
     fragments the candidate streams.
 
-    ``tracer`` (a :class:`repro.obs.trace.Tracer` or ``None``) records a
-    wall-time span per stage-1/stage-2 device dispatch, the fleet's
-    candidate/prune/launch/retrace totals as a ``fleet_solve`` event, and
-    the per-strategy refinement yields as a ``portfolio_yields`` event.
+    ``tracer`` (a :class:`repro.obs.trace.Tracer` or ``None``) records
+    child spans that partition the fleet's host time — ``fleet_tables``,
+    ``fleet_enumerate``, ``fleet_rounds`` (one per phase of a lockstep
+    round), ``fleet_pack`` (one per launch), ``fleet_finish`` — beside a
+    ``stage1_launch``/``stage2_launch`` span per device launch, which
+    holds a ``_dispatch`` (copies in, program enqueued) and a ``_sync``
+    (the blocking copy out) child; the fleet's candidate/prune/launch/
+    retrace totals as a ``fleet_solve`` event; and the per-strategy
+    refinement yields as a ``portfolio_yields`` event.
     """
     tr = as_tracer(tracer)
     I = len(instances)
-    if op_tables is None:
-        op_tables = [build_op_tables(inst) for inst in instances]
-    dims = _fleet_dims(instances, use_wireless, op_tables)
-    eval_tables = _build_eval_stack(instances, dims, use_wireless, op_tables)
-    lb_args = _build_lb_arrays(instances, dims) if use_kernel else None
-    n_dev = jax.local_device_count()
-    fn = _compiled_evaluator(n_dev, dims.m_pad, dims.M_pad, dims.n_chan)
+    with tr.span("fleet_tables"):
+        if op_tables is None:
+            op_tables = [build_op_tables(inst) for inst in instances]
+        dims = _fleet_dims(instances, use_wireless, op_tables)
+        eval_tables = _build_eval_stack(instances, dims, use_wireless, op_tables)
+        lb_args = _build_lb_arrays(instances, dims) if use_kernel else None
+        n_dev = jax.local_device_count()
+        fn = _compiled_evaluator(n_dev, dims.m_pad, dims.M_pad, dims.n_chan)
     t2_0, t1_0 = TRACE_COUNT, LB_TRACE_COUNT
     launches = [0, 0]  # [stage1, stage2]
 
@@ -960,21 +966,33 @@ def _run_fleet(
         refine_patience = 1 if portfolio_mod.spec_length(strategies) == 1 else 3
     if seed_pools is None:
         seed_pools = [None] * I
-    states = [
-        _InstanceState(
-            i,
-            inst,
-            seed=seeds[i],
-            max_enumerate=max_enumerate,
-            n_samples=n_samples,
-            batch_size=batch_size,
-            strategies=strategies,
-            refine_pool=refine_pool,
-            patience=refine_patience,
-            seed_pool=seed_pools[i],
-        )
-        for i, inst in enumerate(instances)
-    ]
+    with tr.span("fleet_enumerate"):
+        states = [
+            _InstanceState(
+                i,
+                inst,
+                seed=seeds[i],
+                max_enumerate=max_enumerate,
+                n_samples=n_samples,
+                batch_size=batch_size,
+                strategies=strategies,
+                refine_pool=refine_pool,
+                patience=refine_patience,
+                seed_pool=seed_pools[i],
+            )
+            for i, inst in enumerate(instances)
+        ]
+
+    def pack(B, rows_of) -> tuple[np.ndarray, np.ndarray]:
+        # rows_of: [(state, rows[<= batch_size, state.n])], one slot each.
+        with tr.span("fleet_pack"):
+            rack = np.zeros((B, dims.n_pad), dtype=np.int32)
+            iid = np.zeros(B, dtype=np.int32)
+            for s, (st, rows) in enumerate(rows_of):
+                lo = s * batch_size
+                rack[lo : lo + rows.shape[0], : st.n] = rows
+                iid[lo : lo + batch_size] = st.idx
+        return rack, iid
 
     def launch_stage2(blocks) -> None:
         # blocks: [(state, block[batch_size, state.n], true_b, tags)],
@@ -982,20 +1000,17 @@ def _run_fleet(
         # solo flow.
         for g0 in range(0, len(blocks), I):
             group = blocks[g0 : g0 + I]
-            rack = np.zeros((B2, dims.n_pad), dtype=np.int32)
-            iid = np.zeros(B2, dtype=np.int32)
-            for s, (st, blk, _tb, _tg) in enumerate(group):
-                lo = s * batch_size
-                rack[lo : lo + batch_size, : st.n] = blk
-                iid[lo : lo + batch_size] = st.idx
-            with tr.span("stage2_launch", rows=B2):
-                vals = np.asarray(
-                    fn(jnp.asarray(rack), jnp.asarray(iid), *eval_tables)
-                )
+            rack, iid = pack(B2, [(st, blk) for st, blk, _tb, _tg in group])
+            with tr.span("stage2_launch", instances=I, rows=B2, n_pad=dims.n_pad):
+                with tr.span("stage2_dispatch"):
+                    vals = fn(jnp.asarray(rack), jnp.asarray(iid), *eval_tables)
+                with tr.span("stage2_sync"):
+                    vals = np.asarray(vals)
             launches[1] += 1
-            for s, (st, blk, tb, tg) in enumerate(group):
-                lo = s * batch_size
-                st.apply_scores(blk, vals[lo : lo + tb], tg)
+            with tr.span("fleet_rounds"):
+                for s, (st, blk, tb, tg) in enumerate(group):
+                    lo = s * batch_size
+                    st.apply_scores(blk, vals[lo : lo + tb], tg)
 
     def launch_stage1(reqs):
         # reqs: [(state, chunk)] -> per-request float32 LB arrays.
@@ -1017,16 +1032,17 @@ def _run_fleet(
                 pieces.append((ri, off, chunk[off : off + batch_size]))
         for g0 in range(0, len(pieces), I):
             group = pieces[g0 : g0 + I]
-            rack = np.zeros((B1, dims.n_pad), dtype=np.int32)
-            iid = np.zeros(B1, dtype=np.int32)
-            for s, (ri, _off, rows) in enumerate(group):
-                st = reqs[ri][0]
-                lo = s * batch_size
-                rack[lo : lo + rows.shape[0], : st.n] = rows
-                iid[lo : lo + batch_size] = st.idx
-            with tr.span("stage1_launch", rows=B1, kernel=True):
-                lbs = np.asarray(
-                    _fleet_lb_device(
+            rack, iid = pack(B1, [(reqs[ri][0], rows) for ri, _off, rows in group])
+            with tr.span(
+                "stage1_launch",
+                instances=I,
+                rows=B1,
+                n_pad=dims.n_pad,
+                n_iters=dims.n_iters,
+                kernel=True,
+            ):
+                with tr.span("stage1_dispatch"):
+                    lbs = _fleet_lb_device(
                         jnp.asarray(rack),
                         jnp.asarray(iid),
                         *lb_args,
@@ -1034,39 +1050,41 @@ def _run_fleet(
                         n_iters=dims.n_iters,
                         contention=contention,
                     )
-                )
+                with tr.span("stage1_sync"):
+                    lbs = np.asarray(lbs)
             launches[0] += 1
-            for s, (ri, off, rows) in enumerate(group):
-                lo = s * batch_size
-                out[ri][off : off + rows.shape[0]] = lbs[lo : lo + rows.shape[0]]
+            with tr.span("fleet_rounds"):
+                for s, (ri, off, rows) in enumerate(group):
+                    lo = s * batch_size
+                    out[ri][off : off + rows.shape[0]] = lbs[lo : lo + rows.shape[0]]
         return out
-
-    def prune_and_score(round_chunks) -> None:
-        prune_reqs = [
-            (st, chunk)
-            for st, chunk in round_chunks
-            if lb_prune and np.isfinite(st.best_val)
-        ]
-        lbs_list = launch_stage1(prune_reqs)
-        lbs_by_state = {
-            id(st): lbs for (st, _), lbs in zip(prune_reqs, lbs_list)
-        }
-        blocks = []
-        for st, chunk in round_chunks:
-            blocks += st.consider(chunk, lbs_by_state.get(id(st)))
-        launch_stage2(blocks)
 
     # Main sweep: one chunk per instance per lockstep round.
     while any(st.pos < st.cands.shape[0] for st in states):
-        round_chunks = []
+        with tr.span("fleet_rounds"):
+            round_chunks = []
+            for st in states:
+                chunk = st.next_chunk()
+                if chunk is not None:
+                    round_chunks.append((st, chunk))
+            prune_reqs = [
+                (st, chunk)
+                for st, chunk in round_chunks
+                if lb_prune and np.isfinite(st.best_val)
+            ]
+        lbs_list = launch_stage1(prune_reqs)
+        with tr.span("fleet_rounds"):
+            lbs_by_state = {
+                id(st): lbs for (st, _), lbs in zip(prune_reqs, lbs_list)
+            }
+            blocks = []
+            for st, chunk in round_chunks:
+                blocks += st.consider(chunk, lbs_by_state.get(id(st)))
+        launch_stage2(blocks)
+    with tr.span("fleet_rounds"):
+        blocks = []
         for st in states:
-            chunk = st.next_chunk()
-            if chunk is not None:
-                round_chunks.append((st, chunk))
-        prune_and_score(round_chunks)
-    blocks = []
-    for st in states:
-        blocks += st.flush_partial()
+            blocks += st.flush_partial()
     launch_stage2(blocks)
     for st in states:
         assert st.best_rack is not None
@@ -1081,79 +1099,81 @@ def _run_fleet(
     for _ in range(refine_rounds):
         if not active:
             break
-        round_chunks = []
-        for st in active:
-            st.prev_best = st.best_val
-            pool, tags = st.portfolio.begin_round(st.best_rack, st.best_val)
-            round_chunks.append((st, pool, tags))
-        prune_reqs = [
-            (st, chunk)
-            for st, chunk, _tags in round_chunks
-            if lb_prune and np.isfinite(st.best_val) and chunk.shape[0]
-        ]
+        with tr.span("fleet_rounds"):
+            round_chunks = []
+            for st in active:
+                st.prev_best = st.best_val
+                pool, tags = st.portfolio.begin_round(st.best_rack, st.best_val)
+                round_chunks.append((st, pool, tags))
+            prune_reqs = [
+                (st, chunk)
+                for st, chunk, _tags in round_chunks
+                if lb_prune and np.isfinite(st.best_val) and chunk.shape[0]
+            ]
         lbs_list = launch_stage1(prune_reqs)
-        lbs_by_state = {id(st): lbs for (st, _), lbs in zip(prune_reqs, lbs_list)}
-        blocks = []
-        for st, chunk, tags in round_chunks:
-            blocks += st.consider(chunk, lbs_by_state.get(id(st)), tags=tags)
-            blocks += st.flush_partial()
+        with tr.span("fleet_rounds"):
+            lbs_by_state = {
+                id(st): lbs for (st, _), lbs in zip(prune_reqs, lbs_list)
+            }
+            blocks = []
+            for st, chunk, tags in round_chunks:
+                blocks += st.consider(chunk, lbs_by_state.get(id(st)), tags=tags)
+                blocks += st.flush_partial()
         launch_stage2(blocks)
-        nxt = []
-        for st in active:
-            st.portfolio.end_round(st.best_rack, st.best_val)
-            st.refine_rounds_run += 1
-            if st.best_val < st.prev_best - 1e-9:
-                st.stall = 0
-            else:
-                st.stall += 1
-            if st.stall < st.patience:
-                nxt.append(st)
+        with tr.span("fleet_rounds"):
+            nxt = []
+            for st in active:
+                st.portfolio.end_round(st.best_rack, st.best_val)
+                st.refine_rounds_run += 1
+                if st.best_val < st.prev_best - 1e-9:
+                    st.stall = 0
+                else:
+                    st.stall += 1
+                if st.stall < st.patience:
+                    nxt.append(st)
         active = nxt
 
-    results = []
-    for st in states:
-        sched = simulate(st.inst, st.best_rack, use_wireless=use_wireless)
-        results.append(
-            VectorizedResult(
-                schedule=sched,
-                makespan=sched.makespan,
-                n_evaluated=st.n_eval,
-                best_assignment=st.best_rack,
-                n_candidates=st.n_cands,
-                n_pruned=st.n_pruned,
-                refine_rounds=st.refine_rounds_run,
-                strategy_stats=st.portfolio.stats,
+    with tr.span("fleet_finish"):
+        results = []
+        for st in states:
+            sched = simulate(st.inst, st.best_rack, use_wireless=use_wireless)
+            results.append(
+                VectorizedResult(
+                    schedule=sched,
+                    makespan=sched.makespan,
+                    n_evaluated=st.n_eval,
+                    best_assignment=st.best_rack,
+                    n_candidates=st.n_cands,
+                    n_pruned=st.n_pruned,
+                    refine_rounds=st.refine_rounds_run,
+                    strategy_stats=st.portfolio.stats,
+                )
             )
-        )
-    stats = {
-        "n_stage1_launches": launches[0],
-        "n_stage2_launches": launches[1],
-        "n_stage1_traces": LB_TRACE_COUNT - t1_0,
-        "n_stage2_traces": TRACE_COUNT - t2_0,
-    }
-    if tr.enabled:
-        tr.count("stage1_launches", launches[0])
-        tr.count("stage2_launches", launches[1])
-        tr.count(
-            "compile_cache_misses",
-            stats["n_stage1_traces"] + stats["n_stage2_traces"],
-        )
-        tr.event(
-            "fleet_solve",
-            n_instances=I,
-            n_candidates=sum(s.n_cands for s in states),
-            n_pruned=sum(s.n_pruned for s in states),
-            n_evaluated=sum(s.n_eval for s in states),
-            **stats,
-        )
-        merged = portfolio_mod.merge_strategy_stats(
-            s.portfolio.stats for s in states
-        )
-        if merged:
+        stats = {
+            "n_stage1_launches": launches[0],
+            "n_stage2_launches": launches[1],
+            "n_stage1_traces": LB_TRACE_COUNT - t1_0,
+            "n_stage2_traces": TRACE_COUNT - t2_0,
+        }
+        if tr.enabled:
+            tr.count("stage1_launches", launches[0])
+            tr.count("stage2_launches", launches[1])
             tr.event(
-                "portfolio_yields",
-                strategies=portfolio_mod.stats_snapshot(merged),
+                "fleet_solve",
+                n_instances=I,
+                n_candidates=sum(s.n_cands for s in states),
+                n_pruned=sum(s.n_pruned for s in states),
+                n_evaluated=sum(s.n_eval for s in states),
+                **stats,
             )
+            merged = portfolio_mod.merge_strategy_stats(
+                s.portfolio.stats for s in states
+            )
+            if merged:
+                tr.event(
+                    "portfolio_yields",
+                    strategies=portfolio_mod.stats_snapshot(merged),
+                )
     return results, stats
 
 
@@ -1228,9 +1248,10 @@ def vectorized_search(
         already covers every canonical assignment). Scored seeds enter
         the refinement portfolio's elite pool like any sweep candidate,
         so crossover can recombine them from round one.
-      tracer: optional :class:`repro.obs.trace.Tracer` recording
-        per-stage device-dispatch spans and the solve's candidate /
-        prune / retrace totals (``None`` = no tracing; bit-identical).
+      tracer: optional :class:`repro.obs.trace.Tracer` recording the
+        fleet driver's phase and launch spans and the solve's candidate /
+        prune / retrace totals, as :func:`schedule_fleet` does (``None`` =
+        no tracing; bit-identical).
 
     Returns:
       :class:`VectorizedResult` (per-strategy refinement counters in
@@ -1305,10 +1326,14 @@ def schedule_fleet(
         tables once and skip the per-launch rebuild; passing ``None``
         builds them here. Results are bit-identical either way.
       tracer: optional :class:`repro.obs.trace.Tracer`. Records a
-        ``schedule_fleet`` span enclosing per-stage device-dispatch
-        spans, plus ``fleet_solve`` (candidates / pruned / launches /
-        retraces) and ``portfolio_yields`` decision events. ``None``
-        (default) traces nothing and is bit-identical.
+        ``schedule_fleet`` span whose children partition it: the host
+        phases (``fleet_tables``, ``fleet_enumerate``, ``fleet_rounds``,
+        ``fleet_pack``, ``fleet_finish``) and one ``stage1_launch`` /
+        ``stage2_launch`` per device launch, each split into
+        ``_dispatch`` and ``_sync``; plus ``fleet_solve`` (candidates /
+        pruned / launches / retraces) and ``portfolio_yields`` decision
+        events (see :func:`_run_fleet`). ``None`` (default) traces
+        nothing and is bit-identical.
       (remaining arguments: see :func:`vectorized_search`.)
 
     Determinism / solo equivalence: with the same seed and parameters,

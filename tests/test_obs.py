@@ -19,10 +19,20 @@ Four layers:
   4. ``StreamingSeries`` edges that the exposition leans on: the
      exact→sketch handoff at ``exact_max``, single-sample quantiles,
      and zero-sample NaN semantics.
+  5. The fleet driver's spans (host phases, launches split into dispatch
+     and sync) on the tracer and in a profiler trace, and the runtime
+     counters: XLA compiles, garbage collections, hooks registered once
+     and never by the untraced path.
 """
 
+import gc
 import json
 import math
+import os
+import subprocess
+import sys
+import weakref
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -74,8 +84,9 @@ def test_span_nesting_and_attrs():
     with tr.span("outer", epoch=3) as outer:
         with tr.span("inner") as inner:
             inner.set(rows=7)
-    assert [s.name for s in tr.spans] == ["outer", "inner"]
-    o, i = tr.spans
+    # A garbage collection inside the spans would add a ``gc`` span.
+    o, i = [s for s in tr.spans if s.name != "gc"]
+    assert [o.name, i.name] == ["outer", "inner"]
     assert (o.depth, o.parent) == (0, -1)
     assert (i.depth, i.parent) == (1, o.index)
     assert i.attrs == {"rows": 7}
@@ -146,7 +157,7 @@ def test_chrome_trace_structure_and_json_safety():
     by_ph = {}
     for e in doc["traceEvents"]:
         by_ph.setdefault(e["ph"], []).append(e)
-    (x,) = by_ph["X"]
+    (x,) = [e for e in by_ph["X"] if e["name"] != "gc"]
     assert x["name"] == "epoch" and x["pid"] == 1 and x["tid"] == 0
     assert x["dur"] >= 0.0 and x["ts"] >= 0.0
     (i,) = by_ph["i"]
@@ -162,7 +173,7 @@ def test_chrome_trace_open_span_gets_zero_duration():
     tr = Tracer()
     tr.span("never_exited")  # deliberately not used as a context manager
     doc = chrome_trace_events(tr)
-    (x,) = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    (x,) = [e for e in doc["traceEvents"] if e["ph"] == "X" and e["name"] != "gc"]
     assert x["dur"] == 0.0
     json.dumps(doc)
 
@@ -440,3 +451,185 @@ def test_series_single_sample_quantiles():
     assert (s.count, s.mean, s.min, s.max) == (1, 42.0, 42.0, 42.0)
     for p in (0.5, 0.9, 0.99):
         assert s.quantile(p) == 42.0
+
+
+# ---------------------------------------------------------------------------
+# Layer 5: fleet-driver spans and runtime counters
+# ---------------------------------------------------------------------------
+
+_FLEET_KW = dict(max_enumerate=64, n_samples=64, batch_size=64,
+                 refine_rounds=2, refine_pool=32)
+_FLEET_PHASES = ("fleet_tables", "fleet_enumerate", "fleet_rounds",
+                 "fleet_pack", "fleet_finish")
+
+
+def _sampled_fleet():
+    """Three instances past ``max_enumerate``: both stages launch."""
+    rng = np.random.default_rng(0)
+    return [
+        ProblemInstance(
+            job=make_onestage_mapreduce(rng, n_map=4, n_reduce=3, rho=1.0),
+            n_racks=4, n_wireless=2,
+        )
+        for _ in range(3)
+    ]
+
+
+def _children(tr, span):
+    return [s for s in tr.spans if s.parent == span.index and s.name != "gc"]
+
+
+def test_fleet_launches_split_into_dispatch_then_sync():
+    tr = Tracer()
+    fleet = schedule_fleet(_sampled_fleet(), tracer=tr, **_FLEET_KW)
+    assert fleet.n_stage1_launches > 0 and fleet.n_stage2_launches > 0
+    for stage, n in ((1, fleet.n_stage1_launches), (2, fleet.n_stage2_launches)):
+        launches = tr.spans_named(f"stage{stage}_launch")
+        assert len(launches) == n
+        assert len(tr.spans_named(f"stage{stage}_dispatch")) == n
+        assert len(tr.spans_named(f"stage{stage}_sync")) == n
+        for sp in launches:
+            kids = _children(tr, sp)
+            assert [k.name for k in kids] == [f"stage{stage}_dispatch",
+                                              f"stage{stage}_sync"]
+            assert kids[0].t1 <= kids[1].t0
+            assert sp.attrs["instances"] == 3
+            assert sp.attrs["rows"] == 3 * 64
+            assert sp.attrs["n_pad"] >= 7
+            if stage == 1:
+                assert sp.attrs["n_iters"] == 6
+
+
+def test_fleet_phases_partition_schedule_fleet():
+    tr = Tracer()
+    schedule_fleet(_sampled_fleet(), tracer=tr, **_FLEET_KW)
+    (top,) = tr.spans_named("schedule_fleet")
+    names = [k.name for k in _children(tr, top)]
+    for once in ("fleet_tables", "fleet_enumerate", "fleet_finish"):
+        assert names.count(once) == 1
+    assert names[:2] == ["fleet_tables", "fleet_enumerate"]
+    assert names[-1] == "fleet_finish"
+    assert set(names) == set(_FLEET_PHASES) | {"stage1_launch", "stage2_launch"}
+    # A collection between two phases is a ``gc`` child of its own.
+    kids = sorted((s for s in tr.spans if s.parent == top.index), key=lambda s: s.t0)
+    for a, b in zip(kids, kids[1:]):
+        assert a.t1 <= b.t0  # siblings never overlap
+    covered = sum(k.duration for k in kids)
+    assert covered <= top.duration
+    assert covered >= 0.9 * top.duration
+
+
+def test_fleet_spans_land_in_the_profiler_trace(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    insts = _sampled_fleet()
+    schedule_fleet(insts, **_FLEET_KW)  # compile outside the profile
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        schedule_fleet(insts, tracer=tr, **_FLEET_KW)
+    (path,) = Path(tmp_path).rglob("*.xplane.pb")
+    host = {
+        e.name
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+    assert {"schedule_fleet", "fleet_enumerate", "stage2_sync"} <= host
+
+
+def test_compile_inside_a_span_is_counted_and_named():
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.arange(5.0)
+    f = jax.jit(lambda v: v * 3.0 + 1.0)
+    tr = Tracer()
+    with tr.span("outer", n_instances=2):
+        with tr.span("launch", rows=5):
+            f(x).block_until_ready()
+    assert tr.counters["xla_compiles"] >= 1
+    assert tr.counters["xla_compile_s"] > 0
+    ev = tr.events_of("compile")[-1]
+    assert ev.attrs["within"] == "launch"
+    assert ev.attrs["path"] == "outer/launch"
+    assert ev.attrs["attrs"] == {"n_instances": 2, "rows": 5}
+    assert ev.span == tr.spans_named("launch")[0].index
+    before = dict(tr.counters)
+    with tr.span("launch", rows=5):
+        f(x).block_until_ready()  # cached: no second executable
+    assert tr.counters["xla_compiles"] == before["xla_compiles"]
+    assert len(tr.events_of("compile")) == before["xla_compiles"]
+
+
+def test_gc_inside_a_span_is_a_child_span():
+    tr = Tracer()
+    with tr.span("outer"):
+        with tr.span("inner") as inner:
+            gc.collect(2)
+    (sp,) = [s for s in tr.spans_named("gc") if s.attrs["generation"] == 2]
+    assert sp.parent == inner._span.index and sp.depth == 2
+    assert sp.attrs["collected"] >= 0
+    assert inner._span.t0 <= sp.t0 <= sp.t1 <= inner._span.t1
+    assert tr.counters["gc_collections"] >= 1
+    assert tr.counters["gc_pause_s"] >= sp.duration > 0
+    # Indices stay positions in the list even with gc spans inserted.
+    assert all(s.index == k for k, s in enumerate(tr.spans))
+    # Outside every span the tracer records nothing.
+    n = tr.counters["gc_collections"]
+    gc.collect(2)
+    assert tr.counters["gc_collections"] == n
+
+
+def test_runtime_hooks_registered_once_and_dropped_tracers_forgotten():
+    from jax._src import monitoring
+
+    from repro.obs import trace
+
+    a, b = Tracer(), Tracer()
+    assert gc.callbacks.count(trace._gc_hook) == 1
+    durations = monitoring.get_event_duration_listeners()
+    assert durations.count(trace._duration_hook) == 1
+    assert monitoring.get_event_listeners().count(trace._event_hook) == 1
+    assert a in trace._LIVE and b in trace._LIVE
+    ref = weakref.ref(b)
+    del b
+    assert ref() is None
+    assert all(t is not None for t in trace._LIVE)
+    assert len([t for t in trace._LIVE if t is a]) == 1
+
+
+def test_untraced_fleet_serve_registers_no_hook():
+    code = (
+        "import gc\n"
+        "from jax._src import monitoring\n"
+        "from repro.obs import trace\n"
+        "from repro.online import OnlineScheduler, production_arrivals\n"
+        "res = OnlineScheduler(6, 2, window=5.0, seed=3, solver_kwargs=dict("
+        "max_enumerate=16, n_samples=16, batch_size=32, refine_rounds=1,"
+        " refine_pool=16)).serve(production_arrivals(3, rate=1 / 10,"
+        " n_jobs=3, n_racks=6, n_wireless=2))\n"
+        "assert res.n_served == 3\n"
+        "assert not trace._HOOKED\n"
+        "assert trace._gc_hook not in gc.callbacks\n"
+        "assert trace._duration_hook not in"
+        " monitoring.get_event_duration_listeners()\n"
+        "assert trace._event_hook not in monitoring.get_event_listeners()\n"
+    )
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(root / "src"),
+                                           os.environ.get("PYTHONPATH", "")]))
+    done = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
+def test_exposition_shows_xla_compiles_not_retraces():
+    tr = Tracer()
+    schedule_fleet(_sampled_fleet(), tracer=tr, **_FLEET_KW)
+    text = prometheus_exposition(tr)
+    assert "# TYPE xla_compiles counter" in text
+    assert "# TYPE gc_collections counter" in text
+    assert "compile_cache_misses" not in text

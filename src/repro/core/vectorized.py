@@ -25,7 +25,15 @@ masks) and solved by a single pair of compiled programs.
   :func:`repro.core.simulator.pad_op_tables` — per-instance tables are
   stacked on a leading axis and gathered per batch row by instance id, so
   candidates of **different** jobs ride in the same launch, and one
-  compiled program serves every fleet whose size bucket matches. Batches
+  compiled program serves every fleet whose size bucket matches. Those
+  gathers run once per launch, before the loop; the scan step holds none.
+  What depends on the candidate's racks but not on the carry (each task's
+  rack, each edge's endpoint racks, the channels both endpoints reach) is
+  computed before the loop as masked maxima, and the step reads its carry
+  through exact one-hot selects (``max(where(one_hot, table, fill))`` over
+  at most 33 entries). Everything the step touches is laid out
+  ``[..., W, B]``, batch rows on the TPU's lanes, so a select is a few
+  vector ops across sublanes where a per-row gather costs far more. Batches
   are sharded across local devices with ``shard_map`` when more than one
   device is present, degrading gracefully to a plain ``jit``.
 
@@ -216,83 +224,90 @@ def _scan_evaluate(
     def take(t):
         return jnp.take(t, inst_id, axis=0)
 
-    # Per-row reachability rows; constant over the scan.
-    reach_b = take(reach)  # [B, M_pad, n_chan]
+    # Batch rows lie on the minor (lane) axis: every array the step reads is
+    # [..., W, B], so a select reduces across sublanes with all lanes busy.
+    def hot(idx, width):  # ids [..., B] -> one-hot [..., width, B]
+        return idx[..., None, :] == jnp.arange(width, dtype=idx.dtype)[:, None]
+
+    def select(tab, h, fill):  # tab [..., W, B], one-hot h -> tab[h], [..., B]
+        return jnp.max(jnp.where(h, tab, fill), axis=-2)
+
+    # Lookups that depend on the candidate's racks and the op tables but not
+    # on the carry, once per launch as [n_ops, ..., B]: the task's rack, the
+    # edge endpoints' racks, and the channels both endpoints reach.
+    rack_t = rack.T  # [n_pad, B]
+    reach_t = jnp.moveaxis(take(reach), 0, -1)  # [M_pad, n_chan, B]
+
+    def rack_of(task):  # task ids [n_ops, B] -> racks [n_ops, B]
+        return select(rack_t, hot(task, n_pad), 0)
+
+    def reach_of(r):  # rack ids [n_ops, B] -> reach rows [n_ops, n_chan, B]
+        return jnp.max(jnp.where(hot(r, M_pad)[:, :, None], reach_t, 0.0), axis=1)
+
+    rv = rack_of(take(op_task).T)
+    ru, rw = rack_of(take(op_src).T), rack_of(take(op_dst).T)
+    # Topology gating: a channel is usable iff both endpoint racks reach it
+    # (row 0, wired, is always reachable).
+    feas = reach_of(ru) * reach_of(rw) > 0
+    # Multi-hot of each task row's gating in-edges over the edge-finish
+    # table (the sentinel row m_pad always reads 0.0).
+    in_mask = jnp.any(jax.nn.one_hot(op_in, m_pad + 1, dtype=bool), axis=2)
 
     # Per-row tables, scan axis leading. Rows of different instances walk
     # different op sequences in lock-step; OP_PAD rows are no-ops.
     xs = (
         take(kind).T, take(op_task).T, take(op_edge).T, take(op_src).T,
-        take(op_dst).T, take(op_p).T, take(op_wired).T, take(op_wireless).T,
-        take(op_local).T, jnp.swapaxes(take(op_in), 0, 1),
+        take(op_p).T, take(op_wired).T, take(op_wireless).T, take(op_local).T,
+        jnp.moveaxis(take(in_mask), 0, -1), rv, ru == rw, feas,
     )
     carry0 = (
-        jnp.zeros((B, M_pad), jnp.float32),      # rack_free
-        take(chan_free0),                        # chan_free (+inf = masked)
-        jnp.zeros((B, n_pad), jnp.float32),      # task_fin
-        jnp.zeros((B, m_pad + 1), jnp.float32),  # edge_fin (+1 sentinel col)
+        jnp.zeros((M_pad, B), jnp.float32),      # rack_free
+        take(chan_free0).T,                      # chan_free (+inf = masked)
+        jnp.zeros((n_pad, B), jnp.float32),      # task_fin
+        jnp.zeros((m_pad + 1, B), jnp.float32),  # edge_fin (+1 sentinel row)
     )
 
-    def pick(tab, idx):  # tab[B, W], idx[B] -> [B]
-        return jnp.take_along_axis(tab, idx[:, None], axis=1)[:, 0]
-
+    # The step reads the carry through exact one-hot selects (every finish
+    # time is >= 0), so its body holds no gather.
     def step(carry, x):
         rack_free, chan_free, task_fin, edge_fin = carry
-        kind_t, t_v, e_id, u, v, p_v, q_w, q_wl, r_l, in_row = x
+        kind_t, t_v, e_id, u, p_v, q_w, q_wl, r_l, in_row, rv, same, feas = x
         is_task = kind_t == OP_TASK
         is_edge = kind_t == OP_EDGE
 
         # Task branch (reads the pre-step carry): start when all gating
         # in-edges have finished and the task's rack is free.
-        ready_t = jnp.max(jnp.take_along_axis(edge_fin, in_row, axis=1), axis=1)
-        rv = pick(rack, t_v)
-        fin_t = jnp.maximum(ready_t, pick(rack_free, rv)) + p_v
+        ready_t = select(edge_fin, in_row, 0.0)
+        rv_hot = hot(rv, M_pad)
+        fin_t = jnp.maximum(ready_t, select(rack_free, rv_hot, -jnp.inf)) + p_v
 
         # Edge branch (reads the pre-step carry; a row is task OR edge at
         # any step, so both branches can share it).
-        ready_e = pick(task_fin, u)
-        same = pick(rack, u) == pick(rack, v)
+        ready_e = select(task_fin, hot(u, n_pad), -jnp.inf)
         fin_local = ready_e + r_l
         # Network path: earliest-finish channel (0 wired, 1.. wireless);
-        # masked channels sit at +inf and are never selected.
+        # masked and unreachable channels sit at +inf and are never selected.
         durs = jnp.concatenate(
-            [q_w[:, None], jnp.broadcast_to(q_wl[:, None], (B, n_chan - 1))],
-            axis=1,
+            [q_w[None], jnp.broadcast_to(q_wl[None], (n_chan - 1, B))], axis=0
         )
-        s = jnp.maximum(ready_e[:, None], chan_free)
-        # Topology gating: a channel is usable iff both endpoint racks reach
-        # it (col 0, wired, is always reachable); infeasible channels sit at
-        # +inf exactly like instance-masked channels.
-        def chan_rows(idx):  # rack ids [B] -> reach rows [B, n_chan]
-            return jnp.take_along_axis(reach_b, idx[:, None, None], axis=1)[:, 0, :]
-
-        feas = chan_rows(pick(rack, u)) * chan_rows(pick(rack, v))
-        f = jnp.where(feas > 0, s + durs, jnp.inf)
-        best = jnp.argmin(f, axis=1)
-        fin_net = jnp.take_along_axis(f, best[:, None], axis=1)[:, 0]
-        new_free = jnp.where(
-            jax.nn.one_hot(best, n_chan, dtype=bool), fin_net[:, None], chan_free
-        )
+        s = jnp.maximum(ready_e[None], chan_free)
+        f = jnp.where(feas, s + durs, jnp.inf)
+        best = jnp.argmin(f, axis=0)
+        fin_net = jnp.min(f, axis=0)
+        new_free = jnp.where(hot(best, n_chan), fin_net[None], chan_free)
         fin_e = jnp.where(same, fin_local, fin_net)
 
         # Merge by per-row op kind (OP_PAD rows change nothing).
-        rack_free = jnp.where(
-            is_task[:, None] & jax.nn.one_hot(rv, M_pad, dtype=bool),
-            fin_t[:, None], rack_free,
-        )
-        task_fin = jnp.where(
-            is_task[:, None] & jax.nn.one_hot(t_v, n_pad, dtype=bool),
-            fin_t[:, None], task_fin,
-        )
-        chan_free = jnp.where((is_edge & ~same)[:, None], new_free, chan_free)
+        rack_free = jnp.where(is_task[None] & rv_hot, fin_t[None], rack_free)
+        task_fin = jnp.where(is_task[None] & hot(t_v, n_pad), fin_t[None], task_fin)
+        chan_free = jnp.where((is_edge & ~same)[None], new_free, chan_free)
         edge_fin = jnp.where(
-            is_edge[:, None] & jax.nn.one_hot(e_id, m_pad + 1, dtype=bool),
-            fin_e[:, None], edge_fin,
+            is_edge[None] & hot(e_id, m_pad + 1), fin_e[None], edge_fin
         )
         return (rack_free, chan_free, task_fin, edge_fin), None
 
     (_, _, task_fin, _), _ = jax.lax.scan(step, carry0, xs)
-    return jnp.max(task_fin, axis=1)
+    return jnp.max(task_fin, axis=0)
 
 
 @functools.lru_cache(maxsize=None)

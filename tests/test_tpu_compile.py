@@ -20,6 +20,7 @@ from repro.core import vectorized as V
 from repro.core.instance import ProblemInstance, Topology
 from repro.kernels import cpm
 from repro.kernels import ops as kops
+from repro.launch.hlo_analysis import _called, _split_computations
 from repro.online import DEFAULT_SOLVER_KWARGS, production_arrivals
 
 BATCH = DEFAULT_SOLVER_KWARGS["batch_size"]
@@ -143,3 +144,32 @@ def test_stage2_evaluator_compiles(one_chip):
     fn = V._compiled_evaluator(1, dims.m_pad, dims.M_pad, dims.n_chan)
     compiled = fn.lower(*args).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_stage2_scan_body_has_no_gather(one_chip):
+    """At the widest production bucket (16 jobs x 512 rows, n_ops 64, m_pad
+    32, indeg_pad 16) the scan step reads its carry and tables through
+    one-hot selects: no gather is reachable from the ``while`` body."""
+    fleet = production_fleet(16)
+    ops = [V.build_op_tables(x) for x in fleet]
+    dims = V._fleet_dims(fleet, True, ops)
+    assert (dims.n_ops, dims.m_pad, dims.indeg_pad) == (64, 32, 16)
+    tables = V._build_eval_stack(fleet, dims, True, ops)
+    B = len(fleet) * BATCH
+    args = [
+        spec((B, dims.n_pad), jnp.int32, one_chip),
+        spec((B,), jnp.int32, one_chip),
+    ] + [spec(t.shape, t.dtype, one_chip) for t in tables]
+    fn = V._compiled_evaluator(1, dims.m_pad, dims.M_pad, dims.n_chan)
+    comps = _split_computations(fn.lower(*args).compile().as_text())
+    loops = [i for c in comps.values() for i in c if i.op == "while"]
+    assert loops
+    seen, todo = set(), [c for i in loops for c in _called(i)]
+    while todo:
+        name = todo.pop()
+        if name in comps and name not in seen:
+            seen.add(name)
+            todo += [c for i in comps[name] for c in _called(i)]
+    ops_in_loop = [i.op for name in seen for i in comps[name]]
+    assert "fusion" in ops_in_loop
+    assert "gather" not in ops_in_loop

@@ -58,6 +58,58 @@ def test_evaluator_equals_host_greedy_reference(case):
     np.testing.assert_array_equal(dev, np.asarray(host, np.float32))
 
 
+@pytest.mark.parametrize("use_wireless", [True, False], ids=["wireless", "wired_only"])
+@pytest.mark.parametrize("seed", range(2))
+def test_fleet_launch_equals_host_greedy_reference(seed, use_wireless):
+    """One stage-2 launch over a fleet that mixes jobs of the op buckets 16,
+    32 and 64, clusters of 3 and 8 racks (M_pad 4 and 8), 1 and 2 wireless
+    subchannels and a topology, rows interleaved and padded: every row's
+    score equals the plain host restatement exactly."""
+    import jax.numpy as jnp
+
+    from repro.core import vectorized as V
+    from repro.core.instance import Topology
+    from repro.core.simulator import greedy_makespan
+
+    rng = np.random.default_rng(seed)
+    jobs = [
+        ("simple_mapreduce", 8), ("random_workflow", 9),
+        ("onestage_mapreduce", 10), ("random_workflow", 1),
+        ("onestage_mapreduce", 6),
+    ]
+    fleet = []
+    for k, (family, n) in enumerate(jobs):
+        job = random_job(rng, family, n_tasks=n, rho=1.0)
+        n_racks, n_wireless = (3, 1) if k % 2 else (8, 2)
+        fleet.append(ProblemInstance(job=job, n_racks=n_racks, n_wireless=n_wireless))
+    reach = rng.random((8, 2)) < 0.5
+    fleet.append(ProblemInstance(
+        job=random_job(rng, "random_workflow", n_tasks=7, rho=1.0),
+        n_racks=8, n_wireless=2, topology=Topology(reach=reach),
+    ))
+    ops = [V.build_op_tables(x) for x in fleet]
+    assert {V._bucket(o.n_ops) for o in ops} >= {16, 32, 64}
+    dims = V._fleet_dims(fleet, use_wireless, ops)
+    tables = V._build_eval_stack(fleet, dims, use_wireless, ops)
+
+    counts = [37, 53, 29, 11, 41, 61]
+    inst_id = rng.permutation(np.repeat(np.arange(len(fleet)), counts))
+    B = 256  # the rows past sum(counts) are padding: instance 0, racks 0
+    iid = np.zeros(B, np.int32)
+    iid[: inst_id.size] = inst_id
+    racks = np.zeros((B, dims.n_pad), np.int32)
+    for b in range(inst_id.size):
+        inst = fleet[iid[b]]
+        racks[b, : inst.job.n_tasks] = rng.integers(0, inst.n_racks, inst.job.n_tasks)
+    fn = V._compiled_evaluator(1, dims.m_pad, dims.M_pad, dims.n_chan)
+    dev = np.asarray(fn(jnp.asarray(racks), jnp.asarray(iid), *tables))
+    host = [
+        greedy_makespan(fleet[i], r[: fleet[i].job.n_tasks], use_wireless=use_wireless)
+        for i, r in zip(iid, racks)
+    ]
+    np.testing.assert_array_equal(dev, np.asarray(host, np.float32))
+
+
 @pytest.mark.parametrize("seed", range(4))
 def test_vectorized_score_upper_bounds_optimum(seed):
     inst = make_instance(seed)

@@ -19,6 +19,11 @@ seed draws a new numbering of each job's tasks and edges, which changes the
 greedy order of every stage-2 score and the rows of both stages, and the
 engine's seed. A run serves several such streams so that its work is an
 average over them.
+
+The numbering still moves the work by some percent from seed to seed. A
+traffic file that names a ``numbering_seed`` draws its streams' numberings
+and engine seeds from that instead: every run seed then serves the same
+streams, and the run seed only orders them.
 """
 
 from __future__ import annotations
@@ -158,6 +163,8 @@ def cell_streams(config: dict, traffic: dict, seed: int) -> list[tuple[int, list
     Each of the traffic's ``streams`` streams is its fixed pool of jobs at
     their fixed arrival times, each job's tasks and edges numbered in an
     order drawn from ``(seed, k)``, with an engine seed drawn from the same.
+    Where the traffic names a ``numbering_seed``, that takes the run seed's
+    place there, and the streams come in an order drawn from the run seed.
     """
     if traffic["arrivals"] != "poisson_pool":
         raise ValueError(f"unknown arrival process {traffic['arrivals']!r}")
@@ -175,9 +182,12 @@ def cell_streams(config: dict, traffic: dict, seed: int) -> list[tuple[int, list
             None if mix["wireless_demand"] is None else tuple(mix["wireless_demand"])
         ),
     )
+    fixed = traffic.get("numbering_seed")
     out = []
     for k in range(traffic["streams"]):
-        rng = np.random.default_rng([seed, k])
+        rng = np.random.default_rng([seed if fixed is None else fixed, k])
         engine_seed = int(rng.integers(2**31))
         out.append((engine_seed, [relabel(j, rng) for j in pool]))
+    if fixed is not None:
+        out = [out[i] for i in np.random.default_rng(seed).permutation(len(out))]
     return out

@@ -5,40 +5,18 @@ The run skips the entry's look for a chip and drives the rest of a run on
 this backend: set-up, window, and the comparison with the reference.
 """
 
-import json
-from pathlib import Path
-
 import jax.numpy as jnp
 import pytest
 
-from benchmarks.chip import control, harness
+from benchmarks.chip import control
 from benchmarks.chip import run as bench_run
 from repro.core import vectorized as V
 from repro.online import cluster
 
-CELLS = Path(__file__).resolve().parent / "cells"
-SEED = 2**31 + 9
-
-
-def tiny_bench() -> dict:
-    bench = json.loads(json.dumps(harness.load_benchmark()))
-    bench["workloads"] = [
-        {"name": f"tiny_{c}.few", "config": f"tiny_{c}", "traffic": "few", "chips": 1,
-         "why": "test size"}
-        for c in ("prod8", "topo8")
-    ]
-    for m in bench["per_layer"]:
-        m["workloads"] = [w["name"] for w in bench["workloads"]]
-    return bench
-
-
-def run_tiny(config: str) -> dict:
-    return bench_run.run(f"tiny_{config}.few", SEED, 0.0, 0, tiny_bench(), root=CELLS)
-
 
 @pytest.mark.parametrize("config", ["prod8", "topo8"])
-def test_sound_program_is_correct(config):
-    line = run_tiny(config)
+def test_sound_program_is_correct(config, tiny):
+    line = tiny.run(config)
     assert line["correct"], line["checks"]
     assert line["failed"] == 0 and line["attempted"] == 16  # 2 streams of 8 jobs
     assert list(line)[-1] == "checks"
@@ -46,10 +24,10 @@ def test_sound_program_is_correct(config):
 
 
 @pytest.mark.parametrize("config", ["prod8", "topo8"])
-def test_control_fails_a_limit(config):
+def test_control_fails_a_limit(config, tiny):
     """The reference in bfloat16, in the program's place, breaks a limit the
     program keeps."""
-    got = control.seed_readings(f"tiny_{config}.few", SEED, tiny_bench(), root=CELLS)
+    got = control.seed_readings(f"tiny_{config}.few", tiny.seed, tiny.bench(), root=tiny.root)
     limits = bench_run.LIMITS
     assert all(got["program"][k] <= limits[k] for k in limits)
     assert any(got["control"][k] > limits[k] for k in got["control"])
@@ -99,23 +77,23 @@ def commit_unchanged(monkeypatch):
     "fault", [alter_stage1, alter_stage2, half_batch, commit_unchanged],
     ids=lambda f: f.__name__,
 )
-def test_fault_makes_the_run_incorrect(fault, monkeypatch):
+def test_fault_makes_the_run_incorrect(fault, monkeypatch, tiny):
     fault(monkeypatch)
-    line = run_tiny("topo8")
+    line = tiny.run("topo8")
     assert not line["correct"]
     broken = [k for k, c in line["checks"].items() if c["value"] > c["limit"]]
     assert broken, line["checks"]
 
 
-def test_traced_run_reads_the_span_metrics():
+def test_traced_run_reads_the_span_metrics(tiny):
     """On this backend the trace holds no TPU plane: the readers of device
-    time find nothing and leave their metrics out; those of spans and
-    counters read."""
-    peak = {"flops_per_s": 1.97e14, "bytes_per_s": 8.19e11}
-    line = bench_run.run("tiny_topo8.few", SEED, 0.0, 1, tiny_bench(), root=CELLS, peak=peak)
+    time find nothing and leave their metrics out; every registered reader
+    of the program's spans and counters reads."""
+    bench = tiny.bench()
+    line = tiny.run("topo8", trace=1, bench=bench)
     assert line["correct"], line["checks"]
     assert line["attempted"] == 8  # the first stream, once
-    assert set(line["metrics"]) == {"commit_ms", "fleet_host_ms", "launches_per_epoch"}
+    assert set(line["metrics"]) == tiny.program_metrics(bench)
     assert line["device"]["busy_s"] == 0.0 and line["device"]["window_s"] > 0
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
     assert list(line)[-1] == "checks"

@@ -1,5 +1,6 @@
 """The harness finds configurations, traffic mixes and metrics by name."""
 
+import functools
 import json
 import shutil
 
@@ -44,31 +45,40 @@ def test_unknown_cell_is_refused():
         harness.cell("prod8.nothing", BENCH)
 
 
-def test_a_new_cell_and_metric_are_files_and_entries_alone(tmp_path):
-    """A later cell, mix and metric: new files beside the old ones and new
-    entries in BENCHMARK.json, with no edit to any file already there."""
+def test_a_new_cell_and_metric_are_files_and_entries_alone(tmp_path, monkeypatch, tiny):
+    """A later cell, mix and span metric: new files beside the old ones and
+    new entries in BENCHMARK.json, with no edit to any file already there.
+    A traced run then reads the new metric with the registered ones."""
     root = tmp_path / "chip"
     shutil.copytree(harness.CHIP_DIR, root, ignore=shutil.ignore_patterns("__pycache__"))
     before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
-    (root / "traffic" / "light.json").write_text(
-        json.dumps({"name": "light", "arrivals": "poisson_pool", "rate": 0.01,
+    (root / "traffic" / "sparse.json").write_text(
+        json.dumps({"name": "sparse", "arrivals": "poisson_pool", "rate": 0.005,
                     "n_jobs": 60, "pool_seed": 0, "streams": 1})
     )
-    (root / "metrics" / "epochs_seen.py").write_text(
-        "def read(red):\n    return float(red.n_epochs) or None\n"
+    (root / "metrics" / "plan_ms.py").write_text(
+        "def read(red):\n"
+        "    t = red.span_seconds('plan_batch')\n"
+        "    return 1e3 * t / red.n_epochs if red.n_epochs and t else None\n"
     )
-    bench = json.loads(json.dumps(BENCH))
+    bench = tiny.bench()
     bench["workloads"].append(
-        {"name": "prod8.light", "config": "prod8", "traffic": "light", "chips": 1,
-         "why": "light load"}
+        {"name": "prod8.sparse", "config": "prod8", "traffic": "sparse", "chips": 1,
+         "why": "sparse load"}
     )
     bench["per_layer"].append(
-        {"name": "epochs_seen", "unit": "count", "better": "higher",
-         "source": "program_counter", "layer": "epoch loop", "moves": "jobs_per_s"}
+        {"name": "plan_ms", "unit": "ms", "better": "lower", "source": "program_span",
+         "layer": "epoch loop", "moves": "epoch_p95_ms"}
     )
-    entry = harness.cell("prod8.light", bench)
+    entry = harness.cell("prod8.sparse", bench)
     assert harness.config(entry["config"], root)["cluster"]["n_racks"] == 8
-    assert harness.traffic(entry["traffic"], root)["rate"] == 0.01
-    reader = harness.metric_reader("epochs_seen", root)
-    assert reader(type("R", (), {"n_epochs": 3})()) == 3.0
+    assert harness.traffic(entry["traffic"], root)["rate"] == 0.005
+
+    # The harness reads its readers from the copy, as a later checkout would.
+    reader = functools.partial(harness.metric_reader, root=root)
+    monkeypatch.setattr(harness, "metric_reader", reader)
+    line = tiny.run("topo8", trace=1, bench=bench)
+    assert "plan_ms" in tiny.program_metrics(bench)
+    assert set(line["metrics"]) == tiny.program_metrics(bench)
+    assert line["metrics"]["plan_ms"]["value"] > 0
     assert all(p.read_bytes() == b for p, b in before.items())

@@ -91,6 +91,27 @@ def test_numbering_streams_renumber_one_pool():
             assert canonical(x) == canonical(z)
 
 
+@pytest.mark.parametrize("seed", [3, 2**31 + 11])
+def test_a_numbering_seed_fixes_the_streams_and_the_seed_orders_them(seed):
+    cfg = harness.config("prod8")
+    tr = dict(harness.traffic("backlog"), streams=6, numbering_seed=5)
+    base = streams.cell_streams(cfg, tr, 0)
+    runs = [streams.cell_streams(cfg, tr, s) for s in (seed, seed, seed + 1)]
+    assert [e for e, _ in runs[0]] == [e for e, _ in runs[1]]
+
+    def key(stream):
+        engine, jobs = stream
+        return engine, tuple((j.p.tobytes(), j.edges.tobytes(), j.d.tobytes()) for j in jobs)
+
+    # The same streams for every run seed, in an order drawn from it.
+    assert len({key(s) for s in base}) == 6
+    assert all(sorted(map(key, r)) == sorted(map(key, base)) for r in runs)
+    assert [key(s) for s in runs[0]] != [key(s) for s in runs[2]]
+    # Without the key the run seed draws the numberings, as before.
+    moved = streams.cell_streams(cfg, dict(tr, numbering_seed=None), seed)
+    assert not {key(s) for s in moved} & {key(s) for s in base}
+
+
 def test_unknown_arrival_process_is_refused():
     with pytest.raises(ValueError):
         streams.cell_streams(

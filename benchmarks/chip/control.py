@@ -56,18 +56,18 @@ def control_gaps(kept) -> dict:
 def seed_readings(workload: str, seed: int, bench: dict, root: Path = harness.CHIP_DIR):
     """Program and control readings of one seed of ``workload``."""
     from benchmarks.chip import run as bench_run
-    from benchmarks.chip.probes import LaunchRecorder
+    from benchmarks.chip.probes import LaunchRecorder, MatchingLog
 
     entry = harness.cell(workload, bench)
     cfg = harness.config(entry["config"], root)
     plans = bench_run.prepare(cfg, harness.traffic(entry["traffic"], root), seed)[:1]
-    with LaunchRecorder() as recorder:
+    with LaunchRecorder() as recorder, MatchingLog() as links:
         bench_run.set_up(cfg, plans, recorder)
         out = bench_run.window(cfg, plans, seed, 0.0, recorder)
     program = bench_run.compare_launches(out["kept"])
     rows = program.pop("rows")
     for plan, res in out["serves"]:
-        program.update(bench_run.audit(plan["jobs"], cfg, res))
+        program.update(bench_run.audit(plan["jobs"], cfg, res, links))
     return {
         "workload": workload,
         "seed": seed,

@@ -7,6 +7,12 @@
   ``repro.core.vectorized`` (``_run_fleet``, ``_fleet_lb_device`` and
   ``_compiled_evaluator``) and keeps device arrays by reference: it adds no
   copy and no sync to the timed path.
+- :class:`MatchingLog` keeps, for each serve's timeline, every change of its
+  usable wireless links (configured by the matching and physically up) with
+  the epoch at which it took effect, for the audit of the link guarantees.
+  It wraps ``OnlineScheduler._epoch_topology``, the one place where a serve
+  moves its link state (outages, then the epoch's re-matching), which runs
+  only under a cluster topology: serves without one never reach it.
 - :class:`EpochClock` is a tracer that stays disabled, so the program takes
   its untraced path, and keeps the start and end of each ``epoch`` span.
 - :class:`AnnotatedTracer` is the program's own tracer that also opens a
@@ -18,13 +24,16 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import weakref
 
 import jax
+import numpy as np
 
 from repro.core import vectorized as V
 from repro.obs.trace import NullTracer, Tracer
+from repro.online.service import OnlineScheduler
 
-__all__ = ["Launch", "LaunchRecorder", "EpochClock", "AnnotatedTracer"]
+__all__ = ["Launch", "LaunchRecorder", "MatchingLog", "EpochClock", "AnnotatedTracer"]
 
 
 @dataclasses.dataclass
@@ -93,6 +102,47 @@ class LaunchRecorder:
         self.counts = {1: 0, 2: 0}
         self.keep = {1: set(keep1), 2: set(keep2)}
         self.kept, self.shapes = [], []
+
+
+def _usable(timeline) -> np.ndarray:
+    return timeline.matching & timeline.link_state
+
+
+class MatchingLog:
+    """Every change of each serve's usable wireless links, by its timeline."""
+
+    def __init__(self):
+        self._logs: weakref.WeakKeyDictionary = weakref.WeakKeyDictionary()
+        self._orig = None
+
+    def __enter__(self) -> "MatchingLog":
+        self._orig = epoch_topology = OnlineScheduler._epoch_topology
+        logs = self._logs
+
+        def epoch_topology_hook(svc, t, st):
+            log = logs.get(st.cluster)
+            if log is None:
+                # The links the timeline starts with, in force until now.
+                log = logs[st.cluster] = [(-np.inf, _usable(st.cluster))]
+            epoch_topology(svc, t, st)
+            now = _usable(st.cluster)
+            if not np.array_equal(now, log[-1][1]):
+                log.append((float(t), now))
+
+        OnlineScheduler._epoch_topology = epoch_topology_hook
+        return self
+
+    def __exit__(self, *exc) -> None:
+        OnlineScheduler._epoch_topology = self._orig
+
+    def of(self, timeline) -> tuple[np.ndarray, np.ndarray]:
+        """``(times float64[L], masks bool[L, n_racks, n_wireless])``: mask
+        ``i`` is in force from ``times[i]`` until ``times[i + 1]``, the first
+        from ``-inf``; empty for a timeline without a cluster topology."""
+        log = self._logs.get(timeline, [])
+        times = np.array([t for t, _ in log], np.float64)
+        shape = (len(log), timeline.n_racks, timeline.n_wireless)
+        return times, np.array([m for _, m in log], bool).reshape(shape)
 
 
 class _EpochCtx:

@@ -18,7 +18,11 @@ reach mask) and answers in straightforward NumPy:
 - :func:`audit_serve`: the committed timeline as a set of guarantees: every
   job served once, no two operations overlapping on one rack or channel,
   each task on its rack for its duration after its predecessors and their
-  transfers.
+  transfers; each wireless transfer one cross edge of its job, between its
+  tasks, on a subchannel that both its racks are linked to by every
+  matching in force while it runs; and no such matching linking a rack to
+  more than ``degree`` subchannels or a subchannel to more than
+  ``channel_degree`` racks.
 
 ``dtype`` is the precision of the arithmetic: float32 as the configuration
 states it, or a lower one for the control.
@@ -196,21 +200,58 @@ def _overlaps(intervals, tol: float) -> int:
     return sum(1 for (_s0, e0), (s1, _e1) in zip(ivs, ivs[1:]) if s1 < e0 - tol)
 
 
+def _in_force(times: np.ndarray, s: float, e: float, tol: float) -> range | None:
+    """Indices of the matchings in force over ``[s, e)``: the one in force at
+    ``s`` and each later one that starts before ``e`` (``None`` where none is
+    in force at ``s``). Entry ``i`` holds from ``times[i]`` to ``times[i + 1]``."""
+    pad = tol * max(1.0, abs(e))
+    lo = int(np.searchsorted(times, s + pad, side="right")) - 1
+    hi = int(np.searchsorted(times, e - pad, side="left"))
+    return None if lo < 0 else range(lo, max(hi, lo + 1))
+
+
+def _most_matched(fits: list[list[int]]) -> int:
+    """Size of a largest matching of intervals to edges: ``fits[i]`` lists the
+    edges interval ``i`` may stand for, and each edge stands for one at most."""
+    owner: dict[int, int] = {}
+
+    def take(i: int, seen: set) -> bool:
+        for e in fits[i]:
+            if e not in seen:
+                seen.add(e)
+                if e not in owner or take(owner[e], seen):
+                    owner[e] = i
+                    return True
+        return False
+
+    return sum(take(i, set()) for i in range(len(fits)))
+
+
 def audit_serve(
     jobs: dict,
     records: list,
     rack_intervals: list,
     wired_intervals: list,
     wireless_intervals: list,
+    links: tuple | None = None,
+    degree: int | None = None,
+    channel_degree: int | None = None,
+    reconfig_id: int | None = None,
     tol: float = 1e-6,
 ) -> dict[str, int]:
     """Counts of broken guarantees in one committed serve (all 0 when sound).
 
-    ``jobs`` maps job id to ``(arrival, Question-like p/edges/d, wired_rate,
+    ``jobs`` maps job id to ``(arrival, p, edges, d, wired_rate,
     wireless_rate)``; ``records`` are the served jobs' records (``job_id``,
     ``arrival``, ``admitted``, ``completion``, ``assignment``); the interval
     lists are the committed ``(start, end, job_id)`` triples per rack, of the
     wired channel, and per wireless subchannel.
+
+    ``links`` is the log of usable wireless links, ``(times float[L], masks
+    bool[L, n_racks, n_wireless])``: mask ``i`` is in force from ``times[i]``
+    until ``times[i + 1]``. ``None`` means no topology: every rack reaches
+    every subchannel and no degree holds. Wireless intervals owned by
+    ``reconfig_id`` are reconfiguration delays, not transfers.
     """
     ids = [int(r.job_id) for r in records]
     served = sorted(set(ids))
@@ -227,6 +268,7 @@ def audit_serve(
     transfers: dict[int, int] = {}
     for _s, _e, j in [iv for ivs in [wired_intervals, *wireless_intervals] for iv in ivs]:
         transfers[int(j)] = transfers.get(int(j), 0) + 1
+    placed: dict[int, tuple] = {}  # job id -> (assignment, task starts)
     broken = 0
     for r in records:
         j = int(r.job_id)
@@ -236,12 +278,9 @@ def audit_serve(
         mine = sorted(on_rack.get(j, []))
         assign = np.asarray(r.assignment)
         n_cross = int(np.sum(assign[edges[:, 0]] != assign[edges[:, 1]])) if len(edges) else 0
-        ok = (
-            len(mine) == p.shape[0]
-            and transfers.get(j, 0) == n_cross
-            and r.admitted >= arrival - tol
-        )
+        ok = len(mine) == p.shape[0] and r.admitted >= arrival - tol
         start = np.full(p.shape[0], np.nan)
+        placed[j] = (assign, start)
         for v in range(p.shape[0]) if ok else ():
             rack = int(assign[v])
             fits = [
@@ -254,6 +293,7 @@ def audit_serve(
             _rk, s, e = mine.pop(fits[0])
             start[v] = s
             ok &= r.admitted - tol <= s and e <= r.completion + tol
+        ok &= transfers.get(j, 0) == n_cross
         if ok:
             end = start + p
             for (u, v), size in zip(edges, d):
@@ -265,8 +305,56 @@ def audit_serve(
                     ok &= gap >= -tol
             ok &= abs(float(np.max(end)) - r.completion) <= tol * max(1.0, r.completion)
         broken += not ok
+
+    wireless_of: dict[int, list] = {}
+    for k, ivs in enumerate(wireless_intervals):
+        for s, e, j in ivs:
+            if int(j) != reconfig_id:
+                wireless_of.setdefault(int(j), []).append((k, float(s), float(e)))
+
+    def linked(ra: int, rb: int, k: int, s: float, e: float) -> bool:
+        if links is None:
+            return True
+        force = _in_force(links[0], s, e, tol)
+        return force is not None and bool(np.all(links[1][force, ra, k] & links[1][force, rb, k]))
+
+    off_links = 0
+    for j, ivs in wireless_of.items():
+        fits: list[list[int]] = [[] for _ in ivs]
+        if j in placed:
+            _arrival, p, edges, d, _wired_rate, wireless_rate = jobs[j]
+            assign, start = placed[j]
+            end = start + p
+            for i, (k, s, e) in enumerate(ivs):
+                for n, ((u, v), size) in enumerate(zip(edges, d)):
+                    ra, rb, need = int(assign[u]), int(assign[v]), size / wireless_rate
+                    if (
+                        ra != rb
+                        and abs((e - s) - need) <= tol * max(1.0, need)
+                        and s >= end[u] - tol * max(1.0, abs(end[u]))
+                        and e <= start[v] + tol * max(1.0, abs(start[v]))
+                        and linked(ra, rb, k, s, e)
+                    ):
+                        fits[i].append(n)
+        off_links += len(ivs) - _most_matched(fits)
+
+    over_degree = 0
+    if links is not None:
+        times, masks = links
+        over = np.zeros(len(times), bool)
+        if degree is not None:
+            over |= (masks.sum(axis=2) > degree).any(axis=1)
+        if channel_degree is not None:
+            over |= (masks.sum(axis=1) > channel_degree).any(axis=1)
+        used: set[int] = set()
+        for ivs in wireless_of.values():
+            for _k, s, e in ivs:
+                used.update(_in_force(times, s, e, tol) or ())
+        over_degree = int(sum(over[i] for i in used))
     return {
         "jobs_missing_or_twice": missing_or_twice,
         "overlaps": overlaps,
         "jobs_off_their_dag": broken,
+        "transfers_off_their_links": off_links,
+        "matchings_over_degree": over_degree,
     }

@@ -21,7 +21,9 @@ with fewer chips than the cell asks for, it prints no result and exits 1.
 3. Correctness, once the window has closed: a sample of the window's
    stage-1 and stage-2 launches, drawn from the seed, against the plain
    reference, and every committed serve against its guarantees
-   (``reference.py``). Each number compared is printed beside its limit.
+   (``reference.py``), under a cluster topology with the matchings that
+   ``probes.MatchingLog`` saw it apply. Each number compared is printed
+   beside its limit.
 
 The last line of stdout is one JSON object.
 """
@@ -104,15 +106,23 @@ def compare_launches(kept) -> dict:
     }
 
 
-def audit(jobs: list[streams.Job], cfg: dict, res) -> dict:
-    cl = cfg["cluster"]
+def audit(jobs: list[streams.Job], cfg: dict, res, links) -> dict:
+    """Broken guarantees of one committed serve; ``links`` is the run's
+    ``probes.MatchingLog``."""
+    from repro.online.cluster import RECONFIG_JOB
+
+    cl, topo = cfg["cluster"], cfg["topology"] or {}
     asked = {
         j.job_id: (j.time, j.p, j.edges, j.d, cl["wired_rate"], cl["wireless_rate"])
         for j in jobs
     }
     tl = res.timeline
     return reference.audit_serve(
-        asked, res.jobs, tl.rack_intervals, tl.wired_intervals, tl.wireless_intervals
+        asked, res.jobs, tl.rack_intervals, tl.wired_intervals, tl.wireless_intervals,
+        links=links.of(tl) if topo else None,
+        degree=topo.get("degree"),
+        channel_degree=topo.get("channel_degree"),
+        reconfig_id=RECONFIG_JOB,
     )
 
 
@@ -220,13 +230,13 @@ def run(
     holds the cell's configuration and traffic files; ``peak`` overrides the
     device's entry in ``peaks.json``."""
     from benchmarks.chip import trace_reduce
-    from benchmarks.chip.probes import LaunchRecorder
+    from benchmarks.chip.probes import LaunchRecorder, MatchingLog
 
     entry = harness.cell(workload, bench)
     cfg = harness.config(entry["config"], root)
     plans = prepare(cfg, harness.traffic(entry["traffic"], root), seed)
     devices = jax.devices()
-    with LaunchRecorder() as recorder:
+    with LaunchRecorder() as recorder, MatchingLog() as links:
         set_up(cfg, plans, recorder)
         setup_s = time.perf_counter() - PROCESS_T0
         print(f"set-up: {setup_s:.3f} s", file=sys.stderr)
@@ -245,10 +255,10 @@ def run(
     served, kept = out["serves"], out["kept"]
     readings = compare_launches(kept)
     rows = readings.pop("rows")
-    broken = {"jobs_missing_or_twice": 0, "overlaps": 0, "jobs_off_their_dag": 0}
+    broken: dict[str, int] = {}
     for plan, res in served:
-        for k, v in audit(plan["jobs"], cfg, res).items():
-            broken[k] += v
+        for k, v in audit(plan["jobs"], cfg, res, links).items():
+            broken[k] = broken.get(k, 0) + v
     readings.update(broken)
     checks = checks_of(readings)
     correct = all(c["value"] <= c["limit"] for c in checks.values())

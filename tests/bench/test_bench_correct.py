@@ -5,13 +5,18 @@ The run skips the entry's look for a chip and drives the rest of a run on
 this backend: set-up, window, and the comparison with the reference.
 """
 
+import dataclasses
+
 import jax.numpy as jnp
 import pytest
 
 from benchmarks.chip import control
 from benchmarks.chip import run as bench_run
 from repro.core import vectorized as V
+from repro.core.instance import Topology
 from repro.online import cluster
+
+LINK_CHECKS = ("transfers_off_their_links", "matchings_over_degree")
 
 
 @pytest.mark.parametrize("config", ["prod8", "topo8"])
@@ -21,6 +26,7 @@ def test_sound_program_is_correct(config, tiny):
     assert line["failed"] == 0 and line["attempted"] == 16  # 2 streams of 8 jobs
     assert list(line)[-1] == "checks"
     assert set(line["metrics"]) == {"jobs_per_s", "epoch_p95_ms", "jct_mean", "setup_s"}
+    assert all(line["checks"][k] == {"value": 0, "limit": 0} for k in LINK_CHECKS)
 
 
 @pytest.mark.parametrize("config", ["prod8", "topo8"])
@@ -73,16 +79,49 @@ def commit_unchanged(monkeypatch):
     )
 
 
+def views_on_every_link(monkeypatch):
+    """The views plan on every candidate link; the timeline keeps matching."""
+    monkeypatch.setattr(
+        cluster.ClusterTimeline,
+        "active_reach",
+        lambda self: None if self.topology is None else self.topology.reach & self.link_state,
+    )
+
+
+def match_over_degree(monkeypatch):
+    """The b-matching ignores the degree limits."""
+    match = Topology.match
+    monkeypatch.setattr(
+        Topology,
+        "match",
+        lambda self, weight, **kw: match(
+            dataclasses.replace(self, degree=None, channel_degree=None), weight, **kw
+        ),
+    )
+
+
 @pytest.mark.parametrize(
-    "fault", [alter_stage1, alter_stage2, half_batch, commit_unchanged],
-    ids=lambda f: f.__name__,
+    "fault, check",
+    [
+        pytest.param(fault, check, id=fault.__name__)
+        for fault, check in [
+            (alter_stage1, None),
+            (alter_stage2, None),
+            (half_batch, None),
+            (commit_unchanged, None),
+            (views_on_every_link, "transfers_off_their_links"),
+            (match_over_degree, "matchings_over_degree"),
+        ]
+    ],
 )
-def test_fault_makes_the_run_incorrect(fault, monkeypatch, tiny):
+def test_fault_makes_the_run_incorrect(fault, check, monkeypatch, tiny):
+    """Each fault breaks some limit; a topology fault breaks its own check."""
     fault(monkeypatch)
     line = tiny.run("topo8")
     assert not line["correct"]
     broken = [k for k, c in line["checks"].items() if c["value"] > c["limit"]]
     assert broken, line["checks"]
+    assert check is None or check in broken, line["checks"]
 
 
 def test_traced_run_reads_the_span_metrics(tiny):

@@ -7,6 +7,7 @@ import shutil
 import pytest
 
 from benchmarks.chip import harness
+from benchmarks.chip import run as bench_run
 
 BENCH = harness.load_benchmark()
 
@@ -81,4 +82,32 @@ def test_a_new_cell_and_metric_are_files_and_entries_alone(tmp_path, monkeypatch
     assert "plan_ms" in tiny.program_metrics(bench)
     assert set(line["metrics"]) == tiny.program_metrics(bench)
     assert line["metrics"]["plan_ms"]["value"] > 0
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
+def test_a_topo8_cell_is_files_and_entries_alone(tmp_path, tiny):
+    """A cell of the reconfigurable deployment: its traffic file, with a
+    ``numbering_seed``, and its BENCHMARK.json entries, with no edit to any
+    file already there. A run is correct and prints the link checks."""
+    root = tmp_path / "chip"
+    shutil.copytree(harness.CHIP_DIR, root, ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    (root / "traffic" / "fixed_few.json").write_text(
+        json.dumps({"name": "fixed_few", "arrivals": "poisson_pool", "rate": 0.02,
+                    "n_jobs": 8, "pool_seed": 0, "streams": 2, "numbering_seed": 0})
+    )
+    bench = tiny.bench()
+    bench["configs"].append(
+        {"name": "topo8", "source": "arXiv:2209.11485", "file": "benchmarks/chip/configs/topo8.json",
+         "reduced": [], "why": "reconfigurable wireless links"}
+    )
+    bench["workloads"].append(
+        {"name": "topo8.fixed_few", "config": "topo8", "traffic": "fixed_few", "chips": 1,
+         "why": "test size"}
+    )
+    line = bench_run.run("topo8.fixed_few", tiny.seed, 0.0, 0, bench, root=root, peak=tiny.peak)
+    assert line["correct"], line["checks"]
+    assert line["attempted"] == 16 and line["failed"] == 0
+    for name in ("transfers_off_their_links", "matchings_over_degree"):
+        assert line["checks"][name] == {"value": 0, "limit": 0}
     assert all(p.read_bytes() == b for p, b in before.items())

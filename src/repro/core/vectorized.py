@@ -91,11 +91,16 @@ __all__ = [
 ]
 
 
+@functools.lru_cache(maxsize=256)
 def enumerate_assignments(n: int, max_racks: int, limit: int | None = None) -> np.ndarray:
     """All canonical task->rack assignments (restricted growth strings).
 
     Canonical = rack labels appear in first-use order, which quotients out
     rack-relabelling symmetry. Returns int32[count, n].
+
+    The result depends only on the arguments, so it is built once per
+    process and shared by every later call with the same arguments; it is
+    read-only, and a caller that needs to write takes a copy.
     """
     out: list[list[int]] = []
 
@@ -113,7 +118,9 @@ def enumerate_assignments(n: int, max_racks: int, limit: int | None = None) -> n
                 return
 
     rec([], 0)
-    return np.asarray(out, dtype=np.int32).reshape(-1, n)
+    rows = np.asarray(out, dtype=np.int32).reshape(-1, n)
+    rows.setflags(write=False)
+    return rows
 
 
 def sample_assignments(
@@ -953,8 +960,10 @@ def _run_fleet(
     ``stage1_launch``/``stage2_launch`` span per device launch, which
     holds a ``_dispatch`` (copies in, program enqueued) and a ``_sync``
     (the blocking copy out) child; the fleet's candidate/prune/launch/
-    retrace totals as a ``fleet_solve`` event; and the per-strategy
-    refinement yields as a ``portfolio_yields`` event.
+    retrace totals as a ``fleet_solve`` event; the per-strategy
+    refinement yields as a ``portfolio_yields`` event; and how often the
+    memo of :func:`enumerate_assignments` served or missed a call as the
+    ``enumerate_cache_hits``/``enumerate_cache_misses`` counters.
     """
     tr = as_tracer(tracer)
     I = len(instances)
@@ -982,6 +991,7 @@ def _run_fleet(
     if seed_pools is None:
         seed_pools = [None] * I
     with tr.span("fleet_enumerate"):
+        memo0 = enumerate_assignments.cache_info() if tr.enabled else None
         states = [
             _InstanceState(
                 i,
@@ -997,6 +1007,10 @@ def _run_fleet(
             )
             for i, inst in enumerate(instances)
         ]
+        if tr.enabled:
+            memo = enumerate_assignments.cache_info()
+            tr.count("enumerate_cache_hits", memo.hits - memo0.hits)
+            tr.count("enumerate_cache_misses", memo.misses - memo0.misses)
 
     def pack(B, rows_of) -> tuple[np.ndarray, np.ndarray]:
         # rows_of: [(state, rows[<= batch_size, state.n])], one slot each.

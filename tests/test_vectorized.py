@@ -34,6 +34,65 @@ def test_enumerate_assignments_canonical():
             mx = max(mx, x)
 
 
+def _restricted_growth_count(n, max_racks):
+    """Set partitions of n tasks into at most max_racks blocks (Stirling
+    numbers of the second kind, summed)."""
+    row = [1]  # S(0, k) for k = 0..
+    for _ in range(n):
+        row = [0] + [k * (row[k] if k < len(row) else 0) + row[k - 1]
+                     for k in range(1, len(row) + 1)]
+    return sum(row[1 : max_racks + 1])
+
+
+ENUMERATION_KEYS = [
+    (n, m, limit) for n in range(1, 11) for m in range(1, 9) for limit in (512, 2001)
+] + [(n, m, None) for n in range(1, 9) for m in range(1, 9)]
+
+
+@pytest.mark.parametrize("n,max_racks,limit", ENUMERATION_KEYS)
+def test_enumeration_memo_equals_an_uncached_build(n, max_racks, limit):
+    got = enumerate_assignments(n, max_racks, limit=limit)
+    fresh = enumerate_assignments.__wrapped__(n, max_racks, limit=limit)
+    assert got is not fresh
+    np.testing.assert_array_equal(got, fresh)
+    assert got.dtype == fresh.dtype == np.int32
+    assert got.shape == fresh.shape
+    count = _restricted_growth_count(n, max_racks)
+    assert got.shape == (count if limit is None else min(count, limit), n)
+    assert enumerate_assignments(n, max_racks, limit=limit) is got
+    with pytest.raises(ValueError):
+        got[0, 0] = 1
+
+
+def test_fleet_is_the_same_with_a_cold_and_a_warm_enumeration_memo():
+    """A fleet of one enumerated and one sampled job (with a seed pool)
+    solves identically whether the memo is empty or already holds every
+    enumeration, and its counters say which it was."""
+    from repro.obs import Tracer
+
+    insts = [make_instance(0, n_tasks=5, n_racks=3), make_instance(1, n_tasks=8, n_racks=8)]
+    pool = np.random.default_rng(2).integers(0, 8, size=(6, 8))
+    kw = dict(max_enumerate=200, n_samples=256, batch_size=128, refine_rounds=2,
+              refine_pool=64, strategies="portfolio", seed_pools=[None, pool], seed=[5, 6])
+
+    enumerate_assignments.cache_clear()
+    cold_tr, warm_tr = Tracer(), Tracer()
+    cold = schedule_fleet(insts, tracer=cold_tr, **kw)
+    warm = schedule_fleet(insts, tracer=warm_tr, **kw)
+    untraced = schedule_fleet(insts, **kw)
+    assert cold.results[1].n_candidates > 200  # the second job is sampled
+    for other in (warm, untraced):
+        for a, b in zip(cold.results, other.results):
+            np.testing.assert_array_equal(a.best_assignment, b.best_assignment)
+            assert a.makespan == b.makespan
+            assert a.n_candidates == b.n_candidates
+            assert a.n_pruned == b.n_pruned
+            assert a.strategy_stats == b.strategy_stats
+    assert cold_tr.counters["enumerate_cache_misses"] > 0
+    assert warm_tr.counters["enumerate_cache_hits"] > 0
+    assert warm_tr.counters["enumerate_cache_misses"] == 0
+
+
 @pytest.mark.parametrize(
     "case", ["wireless", "wired_only", "topology", "edgeless", "nine_tasks"]
 )

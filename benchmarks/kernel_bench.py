@@ -59,6 +59,7 @@ def run():
 def run_search_engine():
     """The two stages of the vectorized search substrate on one size bucket."""
     from repro.core import ProblemInstance, random_job
+    from repro.core.bounds import combined_lower_bounds
     from repro.core.vectorized import (
         batched_lower_bound,
         make_batched_evaluator,
@@ -75,11 +76,11 @@ def run_search_engine():
     _, t = timer(lambda: np.asarray(evaluate(racks)))
     emit("optable_scan_eval_8192xN10", 1e6 * t, f"cands_per_s={racks.shape[0] / t:.0f}")
 
-    batched_lower_bound(inst, racks, use_kernel=True)  # compile the bucket
-    _, t = timer(lambda: batched_lower_bound(inst, racks, use_kernel=True))
+    batched_lower_bound(inst, racks)  # compile the bucket
+    _, t = timer(lambda: batched_lower_bound(inst, racks))
     emit("pallas_cpm_lb_8192xN10", 1e6 * t, f"cands_per_s={racks.shape[0] / t:.0f}")
 
-    _, t = timer(lambda: batched_lower_bound(inst, racks, use_kernel=False))
+    _, t = timer(lambda: combined_lower_bounds(inst, racks))
     emit("edgelist_lb_8192xN10", 1e6 * t, f"cands_per_s={racks.shape[0] / t:.0f}")
 
 

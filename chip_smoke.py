@@ -155,8 +155,8 @@ def stage2_fleet(n_jobs: int, rows_per_job: int, seed: int):
     insts = production_instances(n_jobs, seed)
     insts = [with_random_topology(x, rng) if i % 2 else x for i, x in enumerate(insts)]
     ops = [build_op_tables(x) for x in insts]
-    dims = V._fleet_dims(insts, True, ops)
-    tables = V._build_eval_stack(insts, dims, True, ops)
+    dims = V._fleet_dims(insts, ops)
+    tables = V._build_eval_stack(insts, dims, ops)
     B = n_jobs * rows_per_job
     rack = np.zeros((B, dims.n_pad), np.int32)
     for i, inst in enumerate(insts):
@@ -191,13 +191,13 @@ def phase_stage2(n_jobs: int = 8, rows_per_job: int = 512, seed: int = 1):
 def stage1_call(insts: list[ProblemInstance], rows: int):
     """The fleet's size bucket, and arguments of the engine's stage-1
     program for a ``rows``-row launch."""
-    dims = V._fleet_dims(insts, True)
+    dims = V._fleet_dims(insts)
     args = (
         jnp.zeros((rows, dims.n_pad), jnp.int32),
         jnp.zeros(rows, jnp.int32),
         *V._build_lb_arrays(insts, dims),
     )
-    return dims, args, dict(M_pad=dims.M_pad, n_iters=dims.n_iters, contention=True)
+    return dims, args, dict(M_pad=dims.M_pad, n_iters=dims.n_iters)
 
 
 def phase_fleet(n_jobs: int = 8, seed: int = 2, solver_kwargs=None):
@@ -205,7 +205,7 @@ def phase_fleet(n_jobs: int = 8, seed: int = 2, solver_kwargs=None):
     insts = production_instances(n_jobs, seed)
     dims, args, static = stage1_call(insts, n_jobs * kw["batch_size"])
     assert_mosaic(V._fleet_lb_device.lower(*args, **static).as_text())
-    fleet = V.schedule_fleet(insts, seed=seed, use_kernel=True, **kw)
+    fleet = V.schedule_fleet(insts, seed=seed, **kw)
     for j, (inst, res) in enumerate(zip(insts, fleet.results)):
         sim = simulate(inst, res.best_assignment).makespan
         lb = lower_bound(inst)

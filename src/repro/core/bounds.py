@@ -34,6 +34,9 @@ max(critical_path, rack_load, network_work) is admissible both for exact
 B&B pruning and for the vectorized stage-1 pruner
 (:func:`repro.core.vectorized.batched_lower_bound`, fused on-device via
 :func:`repro.kernels.ops.batched_combined_lb`).
+:func:`combined_lower_bounds` computes the same bound from the edge list
+(:func:`critical_path_bounds` maxed with :func:`contention_lower_bounds`):
+the portable reference the fused path is checked against.
 
 Reachability-aware terms (restricted :class:`~repro.core.instance.Topology`)
 ---------------------------------------------------------------------------
@@ -66,6 +69,8 @@ __all__ = [
     "rack_load_bounds",
     "network_work_bounds",
     "contention_lower_bounds",
+    "critical_path_bounds",
+    "combined_lower_bounds",
     "partial_assignment_bound",
 ]
 
@@ -182,6 +187,61 @@ def contention_lower_bounds(inst: ProblemInstance, racks: np.ndarray) -> np.ndar
     return np.maximum(
         rack_load_bounds(inst, racks), network_work_bounds(inst, racks)
     )
+
+
+def critical_path_bounds(inst: ProblemInstance, racks: np.ndarray) -> np.ndarray:
+    """Per-assignment critical-path bound from the edge list. float32[B].
+
+    dist[v] >= dist[u] + p_u + cost(u, v), where cost is r (same rack) or
+    the optimistic network duration (different racks; q on a forced-wired
+    edge whose rack pair shares no reachable subchannel); converges in
+    <= depth iterations. An edgeless job is bounded by its longest task.
+    """
+    job = inst.job
+    n, m = job.n_tasks, job.n_edges
+    racks = np.asarray(racks, dtype=np.int32)
+    B = racks.shape[0]
+    if m == 0:
+        return np.broadcast_to(np.float32(np.max(job.p)), (B,)).astype(np.float32)
+
+    import jax
+    import jax.numpy as jnp
+
+    net = min_network_durations(inst)
+    p = jnp.asarray(job.p, dtype=jnp.float32)
+    r = jnp.asarray(inst.r_local, dtype=jnp.float32)
+    netc = jnp.asarray(net, dtype=jnp.float32)
+    src = jnp.asarray(job.edges[:, 0].astype(np.int32))
+    dst = jnp.asarray(job.edges[:, 1].astype(np.int32))
+    topo = inst.topology
+    conn = None if topo is None else jnp.asarray(topo.pair_connected())
+    q_wired = jnp.asarray(inst.q_wired, dtype=jnp.float32)
+
+    @jax.jit
+    def lb(rk: jax.Array) -> jax.Array:
+        if conn is None:
+            netc_eff = netc
+        else:
+            # Forced-wired edges (rack pair shares no subchannel) pay q.
+            netc_eff = jnp.where(conn[rk[:, src], rk[:, dst]], netc, q_wired)
+        cost = jnp.where(rk[:, src] == rk[:, dst], r, netc_eff)
+        dist = jnp.zeros((rk.shape[0], n), dtype=jnp.float32)
+
+        def body(_, dist):
+            cand = dist[:, src] + p[src] + cost
+            return jnp.zeros_like(dist).at[:, dst].max(cand)
+
+        dist = jax.lax.fori_loop(0, n - 1, body, dist)
+        return jnp.max(dist + p[None, :], axis=1)
+
+    return np.asarray(lb(jnp.asarray(racks)))
+
+
+def combined_lower_bounds(inst: ProblemInstance, racks: np.ndarray) -> np.ndarray:
+    """Combined §IV-A bound per assignment: :func:`critical_path_bounds`
+    maxed with :func:`contention_lower_bounds`. float32[B]."""
+    extra = contention_lower_bounds(inst, racks).astype(np.float32)
+    return np.maximum(critical_path_bounds(inst, racks), extra)
 
 
 def partial_assignment_bound(

@@ -163,10 +163,12 @@ class _FleetDims:
     n_iters: int
 
 
-def _fleet_dims(instances, use_wireless: bool, op_tables=None) -> _FleetDims:
+def _fleet_dims(instances, op_tables=None) -> _FleetDims:
     """Size bucket of a fleet. ``op_tables`` (one prebuilt ``OpTables`` per
     instance) sizes the evaluator dims; LB-only callers omit it and must
-    not read ``n_ops`` / ``indeg_pad`` (they stay at the bucket floor)."""
+    not read ``n_ops`` / ``indeg_pad`` (they stay at the bucket floor).
+    The channel count is one wired channel plus the fleet's most wireless
+    subchannels (at least one)."""
     n_ops = n = m = M = indeg = wireless = 1
     for i, inst in enumerate(instances):
         if op_tables is not None:
@@ -175,15 +177,14 @@ def _fleet_dims(instances, use_wireless: bool, op_tables=None) -> _FleetDims:
         n = max(n, inst.job.n_tasks)
         m = max(m, inst.job.n_edges)
         M = max(M, inst.n_racks)
-        if use_wireless:
-            wireless = max(wireless, inst.n_wireless)
+        wireless = max(wireless, inst.n_wireless)
     return _FleetDims(
         n_ops=_bucket(n_ops),
         n_pad=_bucket(n),
         m_pad=_bucket(m),
         M_pad=_bucket(M, lo=2),
         indeg_pad=_bucket(indeg, lo=4),
-        n_chan=1 + (wireless if use_wireless else 0),
+        n_chan=1 + wireless,
         n_iters=max(0, n - 1),
     )
 
@@ -349,8 +350,9 @@ def _compiled_evaluator(n_dev: int, m_pad: int, M_pad: int, n_chan: int):
     return jax.jit(sharded)
 
 
-def _build_eval_stack(instances, dims: _FleetDims, use_wireless: bool, op_tables=None):
-    """Stacked device op tables [I, ...] in ``_scan_evaluate`` order."""
+def _build_eval_stack(instances, dims: _FleetDims, op_tables=None):
+    """Stacked device op tables [I, ...] in ``_scan_evaluate`` order. An
+    instance's channels past its own ``1 + n_wireless`` stay masked."""
     I = len(instances)
     fields = {
         "kind": np.zeros((I, dims.n_ops), np.int32),
@@ -376,7 +378,7 @@ def _build_eval_stack(instances, dims: _FleetDims, use_wireless: bool, op_tables
         )
         for name in fields:
             fields[name][i] = getattr(t, name)
-        n_ch = 1 + (inst.n_wireless if use_wireless else 0)
+        n_ch = 1 + inst.n_wireless
         chan_free0[i, :n_ch] = 0.0
         if inst.topology is not None and n_ch > 1:
             reach[i, : inst.n_racks, 1:n_ch] = inst.topology.reach
@@ -386,7 +388,7 @@ def _build_eval_stack(instances, dims: _FleetDims, use_wireless: bool, op_tables
     )
 
 
-def make_batched_evaluator(inst: ProblemInstance, use_wireless: bool = True):
+def make_batched_evaluator(inst: ProblemInstance):
     """Build a fn: rack[B, n] int -> makespan[B] float32 (greedy non-delay).
 
     The fleet-of-one special case of the mega-batch evaluator: pads its
@@ -396,8 +398,8 @@ def make_batched_evaluator(inst: ProblemInstance, use_wireless: bool = True):
     one compiled program per bucket.
     """
     ops = [build_op_tables(inst)]
-    dims = _fleet_dims([inst], use_wireless, ops)
-    tables = _build_eval_stack([inst], dims, use_wireless, ops)
+    dims = _fleet_dims([inst], ops)
+    tables = _build_eval_stack([inst], dims, ops)
     n = inst.job.n_tasks
     n_dev = jax.local_device_count()
     fn = _compiled_evaluator(n_dev, dims.m_pad, dims.M_pad, dims.n_chan)
@@ -470,7 +472,7 @@ def _build_lb_arrays(instances, dims: _FleetDims):
     return tuple(jnp.asarray(a) for a in out)
 
 
-@functools.partial(jax.jit, static_argnames=("M_pad", "n_iters", "contention"))
+@functools.partial(jax.jit, static_argnames=("M_pad", "n_iters"))
 def _fleet_lb_device(
     racks,      # int32[B, n_pad]
     inst_id,    # int32[B]
@@ -488,7 +490,6 @@ def _fleet_lb_device(
     *,
     M_pad: int,
     n_iters: int,
-    contention: bool,
 ):
     """Batched combined §IV-A bound: one device program for the whole fleet.
 
@@ -544,151 +545,81 @@ def _fleet_lb_device(
         ok = None
         mask = None
 
-    if contention:
-        # §IV-A contention terms, accumulated in a fixed sequential order so
-        # an instance's bounds are bit-identical under any fleet padding
-        # (padded tasks/edges contribute exact zeros).
-        def load_body(v, load):
-            rv = jax.lax.dynamic_index_in_dim(racks, v, axis=1, keepdims=False)
-            pv = jax.lax.dynamic_index_in_dim(p_b, v, axis=1, keepdims=False)
-            return load + jnp.where(
-                jax.nn.one_hot(rv, M_pad, dtype=bool), pv[:, None], 0.0
-            )
-
-        load = jax.lax.fori_loop(
-            0, n_pad, load_body, jnp.zeros((B, M_pad), jnp.float32)
+    # §IV-A contention terms, accumulated in a fixed sequential order so an
+    # instance's bounds are bit-identical under any fleet padding (padded
+    # tasks/edges contribute exact zeros).
+    def load_body(v, load):
+        rv = jax.lax.dynamic_index_in_dim(racks, v, axis=1, keepdims=False)
+        pv = jax.lax.dynamic_index_in_dim(p_b, v, axis=1, keepdims=False)
+        return load + jnp.where(
+            jax.nn.one_hot(rv, M_pad, dtype=bool), pv[:, None], 0.0
         )
-        lb_load = jnp.max(load, axis=1)
 
-        nw = take(net_work)
+    load = jax.lax.fori_loop(0, n_pad, load_body, jnp.zeros((B, M_pad), jnp.float32))
+    lb_load = jnp.max(load, axis=1)
 
-        if ok is None:
+    nw = take(net_work)
 
-            def work_body(e, acc):
-                ne = jax.lax.dynamic_index_in_dim(nw, e, axis=1, keepdims=False)
-                se = jax.lax.dynamic_index_in_dim(same, e, axis=1, keepdims=False)
-                return acc + jnp.where(se, 0.0, ne)
+    if ok is None:
 
-            work = jax.lax.fori_loop(
-                0, m_pad, work_body, jnp.zeros((B,), jnp.float32)
-            )
-            extra = jnp.maximum(lb_load, work / take(chan_div))
-        else:
-            # Forced cross edges pay the full wired duration in the
-            # aggregate-work term and, being confined to the single wired
-            # channel, also a serial forced-wired load bound.
-            nw_eff = nw + jnp.where(ok, 0.0, take(uplift))
+        def work_body(e, acc):
+            ne = jax.lax.dynamic_index_in_dim(nw, e, axis=1, keepdims=False)
+            se = jax.lax.dynamic_index_in_dim(same, e, axis=1, keepdims=False)
+            return acc + jnp.where(se, 0.0, ne)
 
-            def work_body_topo(e, acc):
-                work, forced = acc
-                ne = jax.lax.dynamic_index_in_dim(
-                    nw_eff, e, axis=1, keepdims=False
-                )
-                se = jax.lax.dynamic_index_in_dim(same, e, axis=1, keepdims=False)
-                oke = jax.lax.dynamic_index_in_dim(ok, e, axis=1, keepdims=False)
-                return (
-                    work + jnp.where(se, 0.0, ne),
-                    forced + jnp.where(se | oke, 0.0, ne),
-                )
-
-            zero = jnp.zeros((B,), jnp.float32)
-            work, forced = jax.lax.fori_loop(
-                0, m_pad, work_body_topo, (zero, zero)
-            )
-            extra = jnp.maximum(
-                jnp.maximum(lb_load, work / take(chan_div)), forced
-            )
+        work = jax.lax.fori_loop(0, m_pad, work_body, jnp.zeros((B,), jnp.float32))
+        extra = jnp.maximum(lb_load, work / take(chan_div))
     else:
-        extra = jnp.full((B,), -jnp.inf, jnp.float32)
+        # Forced cross edges pay the full wired duration in the aggregate-work
+        # term and, being confined to the single wired channel, also a serial
+        # forced-wired load bound.
+        nw_eff = nw + jnp.where(ok, 0.0, take(uplift))
+
+        def work_body_topo(e, acc):
+            work, forced = acc
+            ne = jax.lax.dynamic_index_in_dim(nw_eff, e, axis=1, keepdims=False)
+            se = jax.lax.dynamic_index_in_dim(same, e, axis=1, keepdims=False)
+            oke = jax.lax.dynamic_index_in_dim(ok, e, axis=1, keepdims=False)
+            return (
+                work + jnp.where(se, 0.0, ne),
+                forced + jnp.where(se | oke, 0.0, ne),
+            )
+
+        zero = jnp.zeros((B,), jnp.float32)
+        work, forced = jax.lax.fori_loop(0, m_pad, work_body_topo, (zero, zero))
+        extra = jnp.maximum(jnp.maximum(lb_load, work / take(chan_div)), forced)
 
     from repro.kernels import ops as kops
 
     return kops.batched_combined_lb(w, p_b, extra, mask=mask, n_iters=n_iters)
 
 
-def batched_lower_bound(
-    inst: ProblemInstance,
-    racks: np.ndarray,
-    use_kernel: bool = False,
-    contention: bool = True,
-) -> np.ndarray:
-    """Combined §IV-A LB per assignment (critical path + contention terms).
+def batched_lower_bound(inst: ProblemInstance, racks: np.ndarray) -> np.ndarray:
+    """Combined §IV-A LB per assignment (critical path + contention terms),
+    through the engine's own stage-1 program (`_fleet_lb_device` ->
+    `repro.kernels.ops.batched_combined_lb`) on dense size-bucketed
+    adjacency blocks. float32[B].
 
-    Critical path: dist[v] >= dist[u] + p_u + cost(u, v) where cost is r
-    (same rack) or the optimistic network duration (different racks);
-    converges in <= depth iterations. With ``contention=True`` (default)
-    the result is maxed with the per-rack work and aggregate channel-work
-    bounds of :mod:`repro.core.bounds`, which is what makes dense instances
-    prunable at all.
-
-    With ``use_kernel=True`` the whole bound runs through the fused Pallas
-    path (`_fleet_lb_device` -> `repro.kernels.ops.batched_combined_lb`) on
-    dense size-bucketed adjacency blocks — the production stage-1 path of
-    `vectorized_search` / `schedule_fleet`. The edge-list jit path is the
-    portable reference oracle.
+    :func:`repro.core.bounds.combined_lower_bounds` computes the same bound
+    from the edge list; it is the portable reference this path is checked
+    against.
     """
-    job = inst.job
-    n, m = job.n_tasks, job.n_edges
     racks = np.asarray(racks, dtype=np.int32)
     B = racks.shape[0]
-
-    if use_kernel:
-        # LB-only dims: no op tables needed (only the n/m/M buckets and the
-        # relaxation depth feed the bound program).
-        dims = _fleet_dims([inst], use_wireless=True)
-        lb_args = _build_lb_arrays([inst], dims)
-        B_pad = _bucket(B)
-        racks_pad = np.zeros((B_pad, dims.n_pad), dtype=np.int32)
-        racks_pad[:B, :n] = racks
-        out = _fleet_lb_device(
-            jnp.asarray(racks_pad),
-            jnp.zeros(B_pad, jnp.int32),
-            *lb_args,
-            M_pad=dims.M_pad,
-            n_iters=dims.n_iters,
-            contention=contention,
-        )
-        return np.asarray(out)[:B]
-
-    if m == 0:
-        base = np.broadcast_to(np.float32(np.max(job.p)), (B,)).astype(np.float32)
-        if contention:
-            extra = bounds_mod.contention_lower_bounds(inst, racks)
-            base = np.maximum(base, extra.astype(np.float32))
-        return base
-    net = bounds_mod.min_network_durations(inst)
-
-    p = jnp.asarray(job.p, dtype=jnp.float32)
-    r = jnp.asarray(inst.r_local, dtype=jnp.float32)
-    netc = jnp.asarray(net, dtype=jnp.float32)
-    src = jnp.asarray(job.edges[:, 0].astype(np.int32))
-    dst = jnp.asarray(job.edges[:, 1].astype(np.int32))
-    topo = inst.topology
-    conn = None if topo is None else jnp.asarray(topo.pair_connected())
-    q_wired = jnp.asarray(inst.q_wired, dtype=jnp.float32)
-
-    @jax.jit
-    def lb(rk: jax.Array) -> jax.Array:
-        if conn is None:
-            netc_eff = netc
-        else:
-            # Forced-wired edges (rack pair shares no subchannel) pay q.
-            netc_eff = jnp.where(conn[rk[:, src], rk[:, dst]], netc, q_wired)
-        cost = jnp.where(rk[:, src] == rk[:, dst], r, netc_eff)
-        dist = jnp.zeros((rk.shape[0], n), dtype=jnp.float32)
-
-        def body(_, dist):
-            cand = dist[:, src] + p[src] + cost
-            return jnp.zeros_like(dist).at[:, dst].max(cand)
-
-        dist = jax.lax.fori_loop(0, n - 1, body, dist)
-        return jnp.max(dist + p[None, :], axis=1)
-
-    out = np.asarray(lb(jnp.asarray(racks)))
-    if contention:
-        extra = bounds_mod.contention_lower_bounds(inst, racks)
-        out = np.maximum(out, extra.astype(np.float32))
-    return out
+    # LB-only dims: no op tables needed (only the n/m/M buckets and the
+    # relaxation depth feed the bound program).
+    dims = _fleet_dims([inst])
+    B_pad = _bucket(B)
+    racks_pad = np.zeros((B_pad, dims.n_pad), dtype=np.int32)
+    racks_pad[:B, : inst.job.n_tasks] = racks
+    out = _fleet_lb_device(
+        jnp.asarray(racks_pad),
+        jnp.zeros(B_pad, jnp.int32),
+        *_build_lb_arrays([inst], dims),
+        M_pad=dims.M_pad,
+        n_iters=dims.n_iters,
+    )
+    return np.asarray(out)[:B]
 
 
 # ---------------------------------------------------------------------------
@@ -933,11 +864,7 @@ def _run_fleet(
     max_enumerate: int,
     n_samples: int,
     seeds: list[int],
-    use_wireless: bool,
     batch_size: int,
-    lb_prune: bool,
-    use_kernel: bool,
-    contention: bool,
     refine_rounds: int,
     refine_pool: int,
     strategies=None,
@@ -970,9 +897,9 @@ def _run_fleet(
     with tr.span("fleet_tables"):
         if op_tables is None:
             op_tables = [build_op_tables(inst) for inst in instances]
-        dims = _fleet_dims(instances, use_wireless, op_tables)
-        eval_tables = _build_eval_stack(instances, dims, use_wireless, op_tables)
-        lb_args = _build_lb_arrays(instances, dims) if use_kernel else None
+        dims = _fleet_dims(instances, op_tables)
+        eval_tables = _build_eval_stack(instances, dims, op_tables)
+        lb_args = _build_lb_arrays(instances, dims)
         n_dev = jax.local_device_count()
         fn = _compiled_evaluator(n_dev, dims.m_pad, dims.M_pad, dims.n_chan)
     t2_0, t1_0 = TRACE_COUNT, LB_TRACE_COUNT
@@ -1045,15 +972,6 @@ def _run_fleet(
         # reqs: [(state, chunk)] -> per-request float32 LB arrays.
         if not reqs:
             return []
-        if not use_kernel:
-            launches[0] += len(reqs)
-            with tr.span("stage1_launch", n_requests=len(reqs), kernel=False):
-                return [
-                    batched_lower_bound(
-                        st.inst, chunk, use_kernel=False, contention=contention
-                    )
-                    for st, chunk in reqs
-                ]
         out = [np.empty(chunk.shape[0], np.float32) for _, chunk in reqs]
         pieces = []
         for ri, (_st, chunk) in enumerate(reqs):
@@ -1068,7 +986,6 @@ def _run_fleet(
                 rows=B1,
                 n_pad=dims.n_pad,
                 n_iters=dims.n_iters,
-                kernel=True,
             ):
                 with tr.span("stage1_dispatch"):
                     lbs = _fleet_lb_device(
@@ -1077,7 +994,6 @@ def _run_fleet(
                         *lb_args,
                         M_pad=dims.M_pad,
                         n_iters=dims.n_iters,
-                        contention=contention,
                     )
                 with tr.span("stage1_sync"):
                     lbs = np.asarray(lbs)
@@ -1088,28 +1004,35 @@ def _run_fleet(
                     out[ri][off : off + rows.shape[0]] = lbs[lo : lo + rows.shape[0]]
         return out
 
+    def lockstep_round(propose, flush: bool) -> None:
+        # One lockstep round. ``propose()`` gives [(state, chunk, tags)];
+        # chunks whose state has a finite incumbent share the stage-1
+        # launches, the survivors are buffered (each state's tail flushed
+        # too when ``flush``) and their blocks share the stage-2 launches.
+        with tr.span("fleet_rounds"):
+            entries = propose()
+            reqs = [
+                (st, chunk)
+                for st, chunk, _tags in entries
+                if np.isfinite(st.best_val) and chunk.shape[0]
+            ]
+        lbs_list = launch_stage1(reqs)
+        with tr.span("fleet_rounds"):
+            lbs_by_state = {id(st): lbs for (st, _), lbs in zip(reqs, lbs_list)}
+            blocks = []
+            for st, chunk, tags in entries:
+                blocks += st.consider(chunk, lbs_by_state.get(id(st)), tags=tags)
+                if flush:
+                    blocks += st.flush_partial()
+        launch_stage2(blocks)
+
+    def sweep_chunks():
+        chunks = ((st, st.next_chunk()) for st in states)
+        return [(st, chunk, None) for st, chunk in chunks if chunk is not None]
+
     # Main sweep: one chunk per instance per lockstep round.
     while any(st.pos < st.cands.shape[0] for st in states):
-        with tr.span("fleet_rounds"):
-            round_chunks = []
-            for st in states:
-                chunk = st.next_chunk()
-                if chunk is not None:
-                    round_chunks.append((st, chunk))
-            prune_reqs = [
-                (st, chunk)
-                for st, chunk in round_chunks
-                if lb_prune and np.isfinite(st.best_val)
-            ]
-        lbs_list = launch_stage1(prune_reqs)
-        with tr.span("fleet_rounds"):
-            lbs_by_state = {
-                id(st): lbs for (st, _), lbs in zip(prune_reqs, lbs_list)
-            }
-            blocks = []
-            for st, chunk in round_chunks:
-                blocks += st.consider(chunk, lbs_by_state.get(id(st)))
-        launch_stage2(blocks)
+        lockstep_round(sweep_chunks, flush=False)
     with tr.span("fleet_rounds"):
         blocks = []
         for st in states:
@@ -1125,30 +1048,19 @@ def _run_fleet(
     # like sweep candidates. An instance stops independently after
     # ``patience`` consecutive non-improving rounds.
     active = [st for st in states if st.sampled] if refine_rounds > 0 else []
+
+    def refine_pools():
+        entries = []
+        for st in active:
+            st.prev_best = st.best_val
+            pool, tags = st.portfolio.begin_round(st.best_rack, st.best_val)
+            entries.append((st, pool, tags))
+        return entries
+
     for _ in range(refine_rounds):
         if not active:
             break
-        with tr.span("fleet_rounds"):
-            round_chunks = []
-            for st in active:
-                st.prev_best = st.best_val
-                pool, tags = st.portfolio.begin_round(st.best_rack, st.best_val)
-                round_chunks.append((st, pool, tags))
-            prune_reqs = [
-                (st, chunk)
-                for st, chunk, _tags in round_chunks
-                if lb_prune and np.isfinite(st.best_val) and chunk.shape[0]
-            ]
-        lbs_list = launch_stage1(prune_reqs)
-        with tr.span("fleet_rounds"):
-            lbs_by_state = {
-                id(st): lbs for (st, _), lbs in zip(prune_reqs, lbs_list)
-            }
-            blocks = []
-            for st, chunk, tags in round_chunks:
-                blocks += st.consider(chunk, lbs_by_state.get(id(st)), tags=tags)
-                blocks += st.flush_partial()
-        launch_stage2(blocks)
+        lockstep_round(refine_pools, flush=True)
         with tr.span("fleet_rounds"):
             nxt = []
             for st in active:
@@ -1165,7 +1077,7 @@ def _run_fleet(
     with tr.span("fleet_finish"):
         results = []
         for st in states:
-            sched = simulate(st.inst, st.best_rack, use_wireless=use_wireless)
+            sched = simulate(st.inst, st.best_rack)
             results.append(
                 VectorizedResult(
                     schedule=sched,
@@ -1211,13 +1123,9 @@ def vectorized_search(
     max_enumerate: int = 200_000,
     n_samples: int = 8192,
     seed: int = 0,
-    use_wireless: bool = True,
     batch_size: int = 8192,
-    lb_prune: bool = True,
-    use_kernel: bool = True,
     refine_rounds: int = 4,
     refine_pool: int = 1024,
-    contention: bool = True,
     strategies=None,
     refine_patience: int | None = None,
     seed_pool: np.ndarray | None = None,
@@ -1236,7 +1144,8 @@ def vectorized_search(
     :func:`schedule_fleet`.
 
     Args:
-      inst: the problem instance.
+      inst: the problem instance (``n_wireless=0`` models wired-only
+        operation).
       max_enumerate: enumerate exhaustively iff the canonical assignment
         count (restricted growth strings) is at most this; else sample.
       n_samples: random candidates in the sampled regime (plus a 2-rack
@@ -1245,19 +1154,11 @@ def vectorized_search(
         draws from ``default_rng(seed + 1)``. Fixed seed + fixed
         parameters => bit-identical results across runs and across fleet
         packings (device scores are float32-deterministic on one backend).
-      use_wireless: expose the instance's wireless subchannels to the
-        evaluator (``False`` models wired-only operation).
       batch_size: stage-2 block size; candidate streams are chunked,
         pruned, and re-blocked to exactly this many rows per launch.
-      lb_prune: enable stage-1 pruning (exact w.r.t. the greedy objective:
-        ``LB(c) >= incumbent`` implies c cannot improve the incumbent).
-      use_kernel: stage-1 via the fused Pallas kernel (else the portable
-        edge-list jit oracle).
       refine_rounds: max refinement rounds (sampled regime only).
       refine_pool: per-round refinement candidate budget, split across the
         portfolio's strategies by recent yield.
-      contention: include the §IV-A contention terms (per-rack work +
-        aggregate channel work) in the stage-1 bound.
       strategies: refinement portfolio spec for
         :func:`repro.core.portfolio.build_strategies`. ``None`` (default)
         is mutation-only local search — bit-for-bit the pre-portfolio
@@ -1293,11 +1194,7 @@ def vectorized_search(
             max_enumerate=max_enumerate,
             n_samples=n_samples,
             seeds=[seed],
-            use_wireless=use_wireless,
             batch_size=batch_size,
-            lb_prune=lb_prune,
-            use_kernel=use_kernel,
-            contention=contention,
             refine_rounds=refine_rounds,
             refine_pool=refine_pool,
             strategies=strategies,
@@ -1313,13 +1210,9 @@ def schedule_fleet(
     max_enumerate: int = 200_000,
     n_samples: int = 8192,
     seed=0,
-    use_wireless: bool = True,
     batch_size: int = 8192,
-    lb_prune: bool = True,
-    use_kernel: bool = True,
     refine_rounds: int = 4,
     refine_pool: int = 1024,
-    contention: bool = True,
     strategies=None,
     refine_patience: int | None = None,
     seed_pools=None,
@@ -1404,11 +1297,7 @@ def schedule_fleet(
             max_enumerate=max_enumerate,
             n_samples=n_samples,
             seeds=seeds,
-            use_wireless=use_wireless,
             batch_size=batch_size,
-            lb_prune=lb_prune,
-            use_kernel=use_kernel,
-            contention=contention,
             refine_rounds=refine_rounds,
             refine_pool=refine_pool,
             strategies=strategies,

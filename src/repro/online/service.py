@@ -47,10 +47,9 @@ Each admission epoch runs the same pipeline:
      for good. A candidate that cannot prove either stays queued (its
      solve still feeds the warm-start incumbents).
 
-  **Warm-started re-optimization.** A job that cannot be admitted
-  (no free rack, or fewer than ``min_free_racks``) stays queued, but is
-  still *planned* in the epoch's mega-batch against its full demanded
-  shape. With ``warm_start=True`` each planning solve (and the eventual
+  **Warm-started re-optimization.** A job that cannot be admitted (no
+  free rack) stays queued, but is still *planned* in the epoch's
+  mega-batch against its full demanded shape. With ``warm_start=True`` each planning solve (and the eventual
   admission solve) seeds the engine's sweep with the job's incumbent
   assignments via the ``seed_pools`` hook — budget-neutral (seeds
   displace an equal number of random samples), so warm vs cold is an
@@ -128,6 +127,12 @@ DEFAULT_SOLVER_KWARGS = dict(
     refine_rounds=2,
     refine_pool=256,
 )
+
+# A job is admitted once at least this many racks are free.
+MIN_FREE_RACKS = 1
+
+# Incumbent assignments remembered per queued job for its warm start.
+SEED_POOL_SIZE = 4
 
 
 def _shape_key(inst) -> tuple:
@@ -375,8 +380,6 @@ class OnlineScheduler:
         heuristic instead of searching).
       warm_start: seed each queued job's re-solve (and its admission
         solve) with its incumbent assignments. Fleet policy only.
-      min_free_racks: admit only when at least this many racks are free;
-        queued jobs below the threshold are planned, not placed.
       require_full_demand: admit a job only when its full demanded shape
         (``inst.n_racks`` racks and ``inst.n_wireless`` subchannels) is
         free, instead of running degraded on a smaller residual. Queued
@@ -403,7 +406,6 @@ class OnlineScheduler:
         same blocking and backfill proofs apply to that order.
       seed: master seed for the per-solve engine seeds (see module
         docstring for the exact derivation).
-      seed_pool_size: incumbents remembered per queued job.
       solver_kwargs: overrides merged over :data:`DEFAULT_SOLVER_KWARGS`
         and passed to :func:`repro.core.vectorized.schedule_fleet`.
       compact_interval: compact the timeline's interval index every this
@@ -543,12 +545,10 @@ class OnlineScheduler:
         window: float = 0.0,
         policy: str = "fleet",
         warm_start: bool = True,
-        min_free_racks: int = 1,
         require_full_demand: bool = False,
         preserve_order: bool = False,
         backfill: bool = False,
         seed: int = 0,
-        seed_pool_size: int = 4,
         solver_kwargs: dict | None = None,
         compact_interval: int = 0,
         replan: str = "always",
@@ -572,10 +572,10 @@ class OnlineScheduler:
                 f"unknown policy {policy!r}; "
                 f"choose 'fleet' or one of {sorted(ONLINE_BASELINES)}"
             )
+        if n_racks < 1:
+            raise ValueError("n_racks must be positive")
         if window < 0.0:
             raise ValueError("window must be non-negative")
-        if not 1 <= min_free_racks <= n_racks:
-            raise ValueError("min_free_racks must be in [1, n_racks]")
         if backfill and not preserve_order:
             raise ValueError(
                 "backfill extends preserve_order head-of-line admission; "
@@ -622,12 +622,10 @@ class OnlineScheduler:
         self.window = float(window)
         self.policy = policy
         self.warm_start = bool(warm_start)
-        self.min_free_racks = int(min_free_racks)
         self.require_full_demand = bool(require_full_demand)
         self.preserve_order = bool(preserve_order)
         self.backfill = bool(backfill)
         self.seed = int(seed)
-        self.seed_pool_size = int(seed_pool_size)
         self.solver_kwargs = dict(DEFAULT_SOLVER_KWARGS)
         if solver_kwargs:
             self.solver_kwargs.update(solver_kwargs)
@@ -953,7 +951,7 @@ class OnlineScheduler:
     def _hol_need(self, inst) -> tuple[int, int]:
         """Racks and wireless subchannels a blocked head-of-line job needs
         free before it can be admitted (demands clamped to the cluster)."""
-        need_r = self.min_free_racks
+        need_r = MIN_FREE_RACKS
         need_w = 0
         if self.require_full_demand:
             need_r = max(need_r, min(inst.n_racks, self.n_racks))
@@ -1058,7 +1056,7 @@ class OnlineScheduler:
         firm: set[int] = set()
         for p in queue:
             inst = p.event.inst
-            ok = pool.size >= self.min_free_racks
+            ok = pool.size >= MIN_FREE_RACKS
             if ok and self.require_full_demand:
                 # Demands are clamped to the cluster shape so an
                 # oversized job can still (eventually) be admitted.
@@ -1177,7 +1175,7 @@ class OnlineScheduler:
         st.counters["pruned"] += fleet.n_pruned
         for p, inst, res in zip(batch, instances, fleet.results):
             p.n_solves += 1
-            p.remember(res, _shape_key(inst), self.seed_pool_size)
+            p.remember(res, _shape_key(inst), SEED_POOL_SIZE)
         plan.results = fleet.results[: len(plan.admit)]
         return plan
 

@@ -26,6 +26,7 @@ from repro.core import (
     schedule_fleet,
     solve_bnb,
 )
+from repro.core.bounds import combined_lower_bounds
 from repro.core.vectorized import (
     batched_lower_bound,
     enumerate_assignments,
@@ -50,8 +51,8 @@ def _instance(seed: int, n_tasks: int, n_racks: int, rho: float, n_wireless: int
 def _check_bound_sandwich(seed, n_tasks, n_racks, rho, n_wireless):
     inst = _instance(seed, n_tasks, n_racks, rho, n_wireless)
     cands = enumerate_assignments(inst.job.n_tasks, inst.n_racks)
-    lbs_kernel = batched_lower_bound(inst, cands, use_kernel=True)
-    lbs_ref = batched_lower_bound(inst, cands, use_kernel=False)
+    lbs_kernel = batched_lower_bound(inst, cands)
+    lbs_ref = combined_lower_bounds(inst, cands)
     np.testing.assert_allclose(lbs_kernel, lbs_ref, rtol=1e-5, atol=1e-3)
 
     scores = np.asarray(make_batched_evaluator(inst)(cands))

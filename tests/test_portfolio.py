@@ -237,7 +237,7 @@ def test_mutation_only_reproduces_pr2_refinement_bit_for_bit():
     n_eval, n_pruned, rounds = base.n_evaluated, base.n_pruned, 0
     for _ in range(R):
         pool = mutate_pool(rng, best, inst, P)
-        lbs = batched_lower_bound(inst, pool, use_kernel=True)
+        lbs = batched_lower_bound(inst, pool)
         keep = lbs < best_val - 1e-6
         n_pruned += int((~keep).sum())
         surv = pool[keep]

@@ -110,7 +110,7 @@ def test_engine_stage1_program_compiles(one_chip, monkeypatch, topology):
             ProblemInstance(job=x.job, n_racks=x.n_racks, n_wireless=x.n_wireless)
             for x in fleet
         ]
-    dims = V._fleet_dims(fleet, True)
+    dims = V._fleet_dims(fleet)
     assert dims.n_pad == 16
     B = len(fleet) * BATCH
     arrays = V._build_lb_arrays(fleet, dims)
@@ -124,7 +124,7 @@ def test_engine_stage1_program_compiles(one_chip, monkeypatch, topology):
     monkeypatch.setattr(kops, "_interpret", lambda: False)
     program = functools.partial(
         V._fleet_lb_device.__wrapped__,
-        M_pad=dims.M_pad, n_iters=dims.n_iters, contention=True,
+        M_pad=dims.M_pad, n_iters=dims.n_iters,
     )
     compiled = jax.jit(program).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -134,8 +134,8 @@ def test_stage2_evaluator_compiles(one_chip):
     """The stage-2 scan evaluator at the serving width: 8 jobs x 512 rows."""
     fleet = production_fleet(8)
     ops = [V.build_op_tables(x) for x in fleet]
-    dims = V._fleet_dims(fleet, True, ops)
-    tables = V._build_eval_stack(fleet, dims, True, ops)
+    dims = V._fleet_dims(fleet, ops)
+    tables = V._build_eval_stack(fleet, dims, ops)
     B = len(fleet) * BATCH
     args = [
         spec((B, dims.n_pad), jnp.int32, one_chip),
@@ -152,9 +152,9 @@ def test_stage2_scan_body_has_no_gather(one_chip):
     one-hot selects: no gather is reachable from the ``while`` body."""
     fleet = production_fleet(16)
     ops = [V.build_op_tables(x) for x in fleet]
-    dims = V._fleet_dims(fleet, True, ops)
+    dims = V._fleet_dims(fleet, ops)
     assert (dims.n_ops, dims.m_pad, dims.indeg_pad) == (64, 32, 16)
-    tables = V._build_eval_stack(fleet, dims, True, ops)
+    tables = V._build_eval_stack(fleet, dims, ops)
     B = len(fleet) * BATCH
     args = [
         spec((B, dims.n_pad), jnp.int32, one_chip),

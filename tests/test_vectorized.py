@@ -5,7 +5,8 @@ regression, one-launch/one-trace compile accounting)."""
 import numpy as np
 import pytest
 
-from repro.core import ProblemInstance, check_feasible, random_job, solve_bnb
+from repro.core import ProblemInstance, check_feasible, random_job, simulate, solve_bnb
+from repro.core.bounds import combined_lower_bounds, critical_path_bounds
 from repro.core.vectorized import (
     batched_lower_bound,
     enumerate_assignments,
@@ -98,7 +99,9 @@ def test_fleet_is_the_same_with_a_cold_and_a_warm_enumeration_memo():
 )
 def test_evaluator_equals_host_greedy_reference(case):
     """Stage-2 device scores equal the plain host restatement of their
-    semantics (op-table order, append-only resources, f32) exactly."""
+    semantics (op-table order, append-only resources, f32) exactly. Wired-only
+    operation is the same job on a cluster without wireless subchannels, and
+    scores as the host's wired-only greedy on the original instance."""
     from repro.core.instance import Topology
     from repro.core.simulator import greedy_makespan
 
@@ -112,8 +115,10 @@ def test_evaluator_equals_host_greedy_reference(case):
         )
     use_wireless = case != "wired_only"
     racks = rng.integers(0, 4, (64, inst.job.n_tasks)).astype(np.int32)
-    dev = np.asarray(make_batched_evaluator(inst, use_wireless=use_wireless)(racks))
     host = [greedy_makespan(inst, r, use_wireless=use_wireless) for r in racks]
+    if not use_wireless:
+        inst = ProblemInstance(job=inst.job, n_racks=4, n_wireless=0)
+    dev = np.asarray(make_batched_evaluator(inst)(racks))
     np.testing.assert_array_equal(dev, np.asarray(host, np.float32))
 
 
@@ -123,7 +128,9 @@ def test_fleet_launch_equals_host_greedy_reference(seed, use_wireless):
     """One stage-2 launch over a fleet that mixes jobs of the op buckets 16,
     32 and 64, clusters of 3 and 8 racks (M_pad 4 and 8), 1 and 2 wireless
     subchannels and a topology, rows interleaved and padded: every row's
-    score equals the plain host restatement exactly."""
+    score equals the plain host restatement exactly. The wired-only launch
+    runs the same jobs on clusters without wireless subchannels against the
+    host's wired-only greedy on the original instances."""
     import jax.numpy as jnp
 
     from repro.core import vectorized as V
@@ -148,8 +155,13 @@ def test_fleet_launch_equals_host_greedy_reference(seed, use_wireless):
     ))
     ops = [V.build_op_tables(x) for x in fleet]
     assert {V._bucket(o.n_ops) for o in ops} >= {16, 32, 64}
-    dims = V._fleet_dims(fleet, use_wireless, ops)
-    tables = V._build_eval_stack(fleet, dims, use_wireless, ops)
+    device_fleet = fleet
+    if not use_wireless:
+        device_fleet = [
+            ProblemInstance(job=x.job, n_racks=x.n_racks, n_wireless=0) for x in fleet
+        ]
+    dims = V._fleet_dims(device_fleet, ops)
+    tables = V._build_eval_stack(device_fleet, dims, ops)
 
     counts = [37, 53, 29, 11, 41, 61]
     inst_id = rng.permutation(np.repeat(np.arange(len(fleet)), counts))
@@ -196,20 +208,22 @@ def test_batched_lower_bound_sound(seed):
 def test_batched_lb_matches_kernel_path(seed=0):
     inst = make_instance(seed)
     cands = enumerate_assignments(inst.job.n_tasks, inst.n_racks)
-    a = batched_lower_bound(inst, cands, use_kernel=False)
-    b = batched_lower_bound(inst, cands, use_kernel=True)
+    a = combined_lower_bounds(inst, cands)
+    b = batched_lower_bound(inst, cands)
     np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("lb", ["oracle", "kernel"])
 @pytest.mark.parametrize("seed", range(3))
-def test_lb_opt_greedy_sandwich(seed, use_kernel):
-    """min LB <= exact optimum <= vectorized greedy score, on both LB paths."""
+def test_lb_opt_greedy_sandwich(seed, lb):
+    """min LB <= exact optimum <= vectorized greedy score, on both LB paths
+    (the edge-list reference and the engine's fused stage-1 program)."""
     inst = make_instance(seed, n_tasks=5, n_racks=3)
     cands = enumerate_assignments(inst.job.n_tasks, inst.n_racks)
-    lbs = batched_lower_bound(inst, cands, use_kernel=use_kernel)
+    bound = combined_lower_bounds if lb == "oracle" else batched_lower_bound
+    lbs = bound(inst, cands)
     opt = solve_bnb(inst, time_limit=30)
-    res = vectorized_search(inst, use_kernel=use_kernel)
+    res = vectorized_search(inst)
     assert float(lbs.min()) <= opt.makespan + 1e-3
     assert opt.makespan <= res.makespan + 0.15
     # per-assignment: LB never exceeds that assignment's greedy score
@@ -218,16 +232,24 @@ def test_lb_opt_greedy_sandwich(seed, use_kernel):
     assert (lbs <= scores + 1e-3).all()
 
 
+def _exhaustive_makespan(inst):
+    """The unpruned sweep's answer: score every canonical assignment and
+    re-execute the first best one on the host simulator."""
+    cands = enumerate_assignments(inst.job.n_tasks, inst.n_racks)
+    scores = np.asarray(make_batched_evaluator(inst)(cands))
+    return cands, scores, simulate(inst, cands[np.argmin(scores)]).makespan
+
+
 def test_lb_pruning_is_exact_and_counted():
     """Pruned search returns the same winner as the unpruned sweep, and the
     candidate accounting (evaluated + pruned = considered) holds."""
     inst = make_instance(1, n_tasks=7, n_racks=4)
     pruned = vectorized_search(inst, batch_size=64)
-    full = vectorized_search(inst, batch_size=64, lb_prune=False)
-    assert pruned.makespan == pytest.approx(full.makespan, abs=1e-6)
+    cands, _scores, full_makespan = _exhaustive_makespan(inst)
+    assert pruned.makespan == pytest.approx(full_makespan, abs=1e-6)
+    assert pruned.n_candidates == len(cands)
     assert pruned.n_evaluated + pruned.n_pruned == pruned.n_candidates
-    assert full.n_pruned == 0 and full.n_evaluated == full.n_candidates
-    assert pruned.n_evaluated <= full.n_evaluated
+    assert pruned.n_evaluated <= len(cands)
 
 
 def test_size_bucket_shares_compiled_program():
@@ -304,12 +326,19 @@ def test_dense_prune_rate_regression():
         np.random.default_rng(0), n_map=4, n_reduce=3, rho=2.0
     )
     inst = ProblemInstance(job=job, n_racks=4, n_wireless=1)
-    old = vectorized_search(inst, batch_size=64, contention=False)
     new = vectorized_search(inst, batch_size=64)
-    full = vectorized_search(inst, batch_size=64, lb_prune=False)
-    assert old.n_pruned == 0, "seed no longer reproduces the 0%-prune gap"
+    cands, scores, full_makespan = _exhaustive_makespan(inst)
+    # Critical path alone: with nothing pruned every 64-row chunk is one
+    # stage-2 block, so the incumbent before a block is the best score of
+    # the blocks before it, and no row's bound may reach it.
+    cp = critical_path_bounds(inst, cands)
+    for lo in range(64, len(cands), 64):
+        incumbent = float(scores[:lo].min())
+        assert (cp[lo : lo + 64] < incumbent - 1e-6).all(), (
+            "seed no longer reproduces the 0%-prune gap"
+        )
     assert new.n_pruned > 0
-    assert new.makespan == pytest.approx(full.makespan, abs=1e-9)
+    assert new.makespan == pytest.approx(full_makespan, abs=1e-9)
     assert new.n_evaluated + new.n_pruned == new.n_candidates
 
 
